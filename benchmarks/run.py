@@ -20,6 +20,8 @@ def main() -> None:
                     help="substring filter: fig3|fig4|comm|kernel|roofline")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_ablation, bench_comm_overhead,
                             bench_drift, bench_eval_engine,
                             bench_fig3_l_sweep, bench_fig4_reliability,

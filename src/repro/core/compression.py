@@ -192,7 +192,7 @@ class Compressor:
         return jax.tree.map(leaf, tree, keys)
 
     def _call_pallas(self, tree, key):
-        """Pallas TPU kernel path (interpret=True on CPU)."""
+        """Pallas kernel path (compiled on a TPU, interpreted elsewhere)."""
         from repro.kernels import ops as kops
         keys = split_key_like(key, tree)
 
@@ -401,7 +401,7 @@ class BlockTopKCodec(Codec):
     """Block-local top-k; uint16 block-local indices, (nb, k) value buffer.
 
     ``use_pallas=True`` routes pack/unpack through the tile-local Pallas
-    kernels (``repro.kernels.pack``, interpret=True on CPU); the jnp path
+    kernels (``repro.kernels.pack``, compiled on a TPU); the jnp path
     is bitwise-identical to the legacy dense-masked ``_block_topk_leaf``.
     """
 
@@ -847,14 +847,13 @@ def _lower_stage0(stages: Tuple[Codec, ...]) -> Tuple[Codec, ...]:
     return tuple(stages)
 
 
-def _qsgd_encode_pallas(stage: QSGDCodec, x, key, interpret: bool = True):
+def _qsgd_encode_pallas(stage: QSGDCodec, x, key):
     """`QSGDCodec.encode` with the grid arithmetic in the Pallas kernel
     (bitwise-identical carrier/scale under a common jit context)."""
     from repro.kernels import ops as kops
     n = int(np.prod(x.shape))
     grid, norm = kops.qsgd_quantize_carrier(
-        x, key, levels=stage.levels, out_dtype=stage._wire_dtype(),
-        interpret=interpret)
+        x, key, levels=stage.levels, out_dtype=stage._wire_dtype())
     meta = _QuantMeta(tuple(x.shape), n, str(x.dtype), levels=stage.levels,
                       omega=_qsgd_omega(n, stage.levels))
     return grid, {"scale": norm.reshape(1)}, meta
@@ -877,7 +876,6 @@ class FusedCodec(CompressionPipeline):
     """
 
     fused: bool = True
-    interpret: bool = True
 
     @classmethod
     def wrap(cls, pipeline: CompressionPipeline, fused: bool = True,
@@ -894,8 +892,7 @@ class FusedCodec(CompressionPipeline):
             return super()._encode_leaf(stages, x, v, leaf_key)
         from repro.kernels import ops as kops
         vals, idx = kops.fused_delta_pack(
-            x, v, ratio=s0.ratio, block_size=s0.block_size,
-            interpret=self.interpret)
+            x, v, ratio=s0.ratio, block_size=s0.block_size)
         carrier = vals
         auxes = [{"idx": idx}]
         metas = [_SparseMeta(tuple(x.shape), x.size, vals.shape[1],
@@ -904,8 +901,8 @@ class FusedCodec(CompressionPipeline):
             stage = stages[si]
             skey = _stage_key(leaf_key, si)
             if isinstance(stage, QSGDCodec):
-                carrier, aux, meta = _qsgd_encode_pallas(
-                    stage, carrier, skey, interpret=self.interpret)
+                carrier, aux, meta = _qsgd_encode_pallas(stage, carrier,
+                                                         skey)
             else:
                 carrier, aux, meta = stage.encode(carrier, skey)
             auxes.append(aux)

@@ -392,16 +392,6 @@ class ShardEvalEngine:
                             ) * self.num_shards
         self._fns = {}
 
-    def _shard_map(self, fn, in_specs, out_specs):
-        try:
-            from jax import shard_map as _sm            # jax >= 0.6
-            return _sm(fn, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-        except (ImportError, TypeError):
-            from jax.experimental.shard_map import shard_map as _sm
-            return _sm(fn, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
-
     def place(self, stacked):
         """device_put the stacked bank with the node axis (dim 1) sharded."""
         s = NamedSharding(self.mesh, P(None, self.fed_axis))
@@ -459,9 +449,9 @@ class ShardEvalEngine:
                                        init_accum(self.num_bins))
             in_specs = ((stacked_specs, P(), P(), P()) if weighted
                         else (stacked_specs, P(), P()))
-            fn = self._shard_map(make_local(weighted),
-                                 in_specs=in_specs,
-                                 out_specs=accum_specs)
+            fn = jax.shard_map(make_local(weighted), mesh=self.mesh,
+                               in_specs=in_specs, out_specs=accum_specs,
+                               check_vma=False)
             self._fns[key] = jax.jit(fn)
         return self._fns[key]
 

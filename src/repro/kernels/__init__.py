@@ -7,7 +7,18 @@
 * qsgd — stochastic quantization (paper ref [26]) with contraction scaling.
 
 ops.py: jit'd wrappers (padding/tiling); ref.py: pure-jnp oracles.
-Validated with interpret=True on CPU; interpret=False on real TPU.
-EXAMPLE.md documents the layout convention.
+The wrappers take their mode from :func:`interpret_mode`: compiled by
+Mosaic on a TPU, the Pallas interpreter on every other backend. Only the
+low-level ``*_pallas`` functions take ``interpret`` explicitly, so tests
+can compile them for a described TPU from a CPU host.
 """
-from repro.kernels import ops, ref  # noqa: F401
+import jax
+
+
+def interpret_mode() -> bool:
+    """True exactly when the default backend is not a TPU."""
+    return jax.default_backend() != "tpu"
+
+
+# after interpret_mode: ops imports it from this package
+from repro.kernels import ops, ref  # noqa: E402,F401

@@ -10,6 +10,10 @@ MXU/VPU friendly:
     P(τ) = count(|x| >= τ) >= k   is monotone in τ;
     40 float32 bisection steps isolate the k-th magnitude per block.
 
+Mosaic has no ``cumsum``, so the index-order rank that breaks ties at the
+threshold is a matmul against a triangular 0/1 matrix
+(:func:`_row_prefix_count`), exact for any block size.
+
 Layout: input reshaped to (num_blocks, block_size); one grid row processes
 ``ROWS_PER_TILE`` blocks; block_size is a multiple of 128 (lane width).
 """
@@ -23,11 +27,11 @@ from jax.experimental import pallas as pl
 
 ROWS_PER_TILE = 8
 BISECT_ITERS = 40
+LANES = 128
 
 
-def _block_topk_kernel(x_ref, o_ref, *, k: int):
-    x = x_ref[...]                                     # (rows, block_size)
-    mag = jnp.abs(x.astype(jnp.float32))
+def _bisect_threshold(mag, k: int):
+    """Per-row ``(lo, hi)`` with count(mag >= lo) >= k > count(mag >= hi)."""
     hi = jnp.max(mag, axis=1, keepdims=True) + 1.0     # P(hi) = False
     lo = jnp.zeros_like(hi)                            # P(lo) = True
 
@@ -38,7 +42,38 @@ def _block_topk_kernel(x_ref, o_ref, *, k: int):
         pred = cnt >= k
         return jnp.where(pred, mid, lo), jnp.where(pred, hi, mid)
 
-    lo, hi = jax.lax.fori_loop(0, BISECT_ITERS, body, (lo, hi))
+    return jax.lax.fori_loop(0, BISECT_ITERS, body, (lo, hi))
+
+
+def _row_prefix_count(mask):
+    """Inclusive prefix count of a ``(rows, bs)`` bool mask along each row.
+
+    Each 128-lane chunk is multiplied by an upper-triangular 0/1 matrix on
+    the MXU and the chunk totals carry into the next chunk. Operands are
+    0/1 (exact in bf16) and the f32 accumulator sums at most 128 ones, so
+    the counts are exact integers.
+    """
+    rows, bs = mask.shape
+    r = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    tri = (r <= c).astype(jnp.float32).astype(jnp.bfloat16)
+    m = mask.astype(jnp.float32).astype(jnp.bfloat16)
+    carry = jnp.zeros((rows, 1), jnp.float32)
+    parts = []
+    for s in range(0, bs, LANES):
+        w = min(LANES, bs - s)
+        pre = jnp.dot(m[:, s:s + w], tri[:w, :w],
+                      preferred_element_type=jnp.float32) + carry
+        parts.append(pre)
+        carry = pre[:, w - 1:w]
+    out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    return out.astype(jnp.int32)
+
+
+def _block_topk_kernel(x_ref, o_ref, *, k: int):
+    x = x_ref[...]                                     # (rows, block_size)
+    mag = jnp.abs(x.astype(jnp.float32))
+    lo, hi = _bisect_threshold(mag, k)
     # Exact-k under ties (invariants: count(>= lo) >= k, count(>= hi) < k):
     # everything strictly above the threshold survives, then the
     # tied-at-threshold group fills the remaining slots in index order —
@@ -47,12 +82,12 @@ def _block_topk_kernel(x_ref, o_ref, *, k: int):
     mask_def = mag >= hi
     mask_tie = (mag >= lo) & ~mask_def
     n_def = jnp.sum(mask_def.astype(jnp.int32), axis=1, keepdims=True)
-    pos_tie = n_def + jnp.cumsum(mask_tie.astype(jnp.int32), axis=1) - 1
+    pos_tie = n_def + _row_prefix_count(mask_tie) - 1
     mask = mask_def | (mask_tie & (pos_tie < k))
     o_ref[...] = jnp.where(mask, x, jnp.zeros_like(x))
 
 
-def block_topk_pallas(x2d: jnp.ndarray, k: int, *, interpret: bool = True
+def block_topk_pallas(x2d: jnp.ndarray, k: int, *, interpret: bool
                       ) -> jnp.ndarray:
     """x2d (num_blocks, block_size) -> same shape, top-k per row kept."""
     nb, bs = x2d.shape

@@ -24,8 +24,8 @@ read itself, one kernel per stage-pair of the ``"block_topk|qsgd"`` DSL:
 Eligibility and fallback semantics live in ``core/compression.py``
 (:class:`FusedCodec`); the two-pass path is kept verbatim as the bitwise
 reference oracle behind ``fused=False``. Layout conventions follow
-``pack.py`` (f32 tiles of ``ROWS_PER_TILE`` blocks, ``interpret=True``
-validation mode on CPU; the TPU path would pad ``k`` to a lane multiple).
+``pack.py`` (f32 tiles of ``ROWS_PER_TILE`` blocks, slots padded to a lane
+multiple inside the kernel and sliced back to ``k`` by the wrapper).
 """
 from __future__ import annotations
 
@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.pack import ROWS_PER_TILE, _pack_tile
+from repro.kernels.pack import (ROWS_PER_TILE, _lane_pad, _pack_tile,
+                                _row_tiled_call)
 
 
 def _delta_pack_kernel(t_ref, v_ref, vals_ref, idx_ref, *, k: int):
@@ -49,23 +50,16 @@ def _delta_pack_kernel(t_ref, v_ref, vals_ref, idx_ref, *, k: int):
 
 
 def delta_pack_pallas(t2d: jnp.ndarray, v2d: jnp.ndarray, k: int, *,
-                      interpret: bool = True):
+                      interpret: bool):
     """(theta, v) as (num_blocks, block_size) -> (vals (nb, k), idx i32)."""
     nb, bs = t2d.shape
     assert v2d.shape == (nb, bs), (t2d.shape, v2d.shape)
-    assert nb % ROWS_PER_TILE == 0, f"pad num_blocks to {ROWS_PER_TILE}"
-    grid = (nb // ROWS_PER_TILE,)
-    return pl.pallas_call(
-        functools.partial(_delta_pack_kernel, k=k),
-        grid=grid,
-        in_specs=[pl.BlockSpec((ROWS_PER_TILE, bs), lambda i: (i, 0)),
-                  pl.BlockSpec((ROWS_PER_TILE, bs), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((ROWS_PER_TILE, k), lambda i: (i, 0)),
-                   pl.BlockSpec((ROWS_PER_TILE, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, k), t2d.dtype),
-                   jax.ShapeDtypeStruct((nb, k), jnp.int32)],
-        interpret=interpret,
-    )(t2d, v2d)
+    kp = _lane_pad(k)
+    vals, idx = _row_tiled_call(
+        functools.partial(_delta_pack_kernel, k=k), nb, [bs, bs],
+        [jax.ShapeDtypeStruct((nb, kp), t2d.dtype),
+         jax.ShapeDtypeStruct((nb, kp), jnp.int32)], interpret)(t2d, v2d)
+    return vals[:, :k], idx[:, :k]
 
 
 def _grid_quant_kernel(x_ref, u_ref, norm_ref, q_ref, *, levels: int):
@@ -79,7 +73,7 @@ def _grid_quant_kernel(x_ref, u_ref, norm_ref, q_ref, *, levels: int):
 
 def grid_quant_pallas(x: jnp.ndarray, uniform: jnp.ndarray,
                       norm: jnp.ndarray, levels: int, out_dtype, *,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool) -> jnp.ndarray:
     """Quantize a packed (rows, k) carrier onto the signed QSGD grid.
 
     Emits the integer carrier ``sign(x)·q`` that crosses the wire

@@ -34,7 +34,7 @@ def _fused_update_kernel(theta_ref, vbar_ref, v_ref, noise_ref, o_ref,
 
 
 def fused_update_pallas(theta, vbar, v, noise, zeta: float, noise_scale: float,
-                        *, interpret: bool = True):
+                        *, interpret: bool):
     """All inputs (R, C) with R % TILE_R == 0 and C == TILE_C."""
     r, c = theta.shape
     assert r % TILE_R == 0 and c == TILE_C, (r, c)

@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle flattening/padding of arbitrary param leaves into the kernels' tiled
-2D layouts, and expose pytree-level entry points used by the CD-BFL round
-when ``use_pallas=True``. ``interpret=True`` everywhere on CPU (the brief's
-validation mode); on TPU the same code path sets interpret=False.
+2D layouts, and expose pytree-level entry points. Every wrapper runs its
+kernel in the mode :func:`repro.kernels.interpret_mode` picks from the
+platform: compiled on a TPU, interpreted elsewhere.
 """
 from __future__ import annotations
 
@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.block_topk import ROWS_PER_TILE, block_topk_pallas
 from repro.kernels.fused_compress import delta_pack_pallas, grid_quant_pallas
 from repro.kernels.fused_update import TILE_C, TILE_R, fused_update_pallas
@@ -40,13 +41,13 @@ def _unpad(x2d: jnp.ndarray, n: int, shape) -> jnp.ndarray:
 # block top-k
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("ratio", "block_size", "interpret"))
-def block_topk(x: jnp.ndarray, ratio: float = 0.01, block_size: int = 1024,
-               interpret: bool = True) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("ratio", "block_size"))
+def block_topk(x: jnp.ndarray, ratio: float = 0.01,
+               block_size: int = 1024) -> jnp.ndarray:
     """Leaf-level block top-k. Keeps ceil(ratio·block_size) per block."""
     k = max(1, int(np.ceil(ratio * block_size)))
     x2d, n = _pad_to_2d(x, block_size, ROWS_PER_TILE)
-    out = block_topk_pallas(x2d, k, interpret=interpret)
+    out = block_topk_pallas(x2d, k, interpret=interpret_mode())
     return _unpad(out, n, x.shape)
 
 
@@ -54,10 +55,9 @@ def block_topk(x: jnp.ndarray, ratio: float = 0.01, block_size: int = 1024,
 # block top-k wire format: tile-local pack / unpack (DESIGN.md §2)
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("ratio", "block_size",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("ratio", "block_size"))
 def block_topk_pack(x: jnp.ndarray, ratio: float = 0.01,
-                    block_size: int = 1024, interpret: bool = True):
+                    block_size: int = 1024):
     """Pack a leaf into the wire format: (vals (nb, k), idx uint16).
 
     ``nb = ceil(x.size / block_size)`` — the all-zero rows the kernel adds
@@ -70,14 +70,13 @@ def block_topk_pack(x: jnp.ndarray, ratio: float = 0.01,
     k = max(1, int(np.ceil(ratio * block_size)))
     nb = max(1, -(-x.size // block_size))
     x2d, _ = _pad_to_2d(x, block_size, ROWS_PER_TILE)
-    vals, idx = pack_topk_pallas(x2d, k, interpret=interpret)
+    vals, idx = pack_topk_pallas(x2d, k, interpret=interpret_mode())
     return vals[:nb], idx[:nb].astype(jnp.uint16)
 
 
-@functools.partial(jax.jit, static_argnames=("n", "shape", "block_size",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("n", "shape", "block_size"))
 def block_topk_unpack(vals: jnp.ndarray, idx: jnp.ndarray, n: int, shape,
-                      block_size: int = 1024, interpret: bool = True):
+                      block_size: int = 1024):
     """Scatter a packed (vals, idx) payload back to the dense masked leaf.
 
     Re-pads the block rows to the kernel's tile multiple (zero vals at
@@ -87,7 +86,8 @@ def block_topk_unpack(vals: jnp.ndarray, idx: jnp.ndarray, n: int, shape,
     nb_pad = -(-nb // ROWS_PER_TILE) * ROWS_PER_TILE
     vals = jnp.pad(vals, ((0, nb_pad - nb), (0, 0)))
     idx = jnp.pad(idx.astype(jnp.int32), ((0, nb_pad - nb), (0, 0)))
-    dense2d = unpack_topk_pallas(vals, idx, block_size, interpret=interpret)
+    dense2d = unpack_topk_pallas(vals, idx, block_size,
+                                 interpret=interpret_mode())
     return _unpad(dense2d, n, shape)
 
 
@@ -95,9 +95,8 @@ def block_topk_unpack(vals: jnp.ndarray, idx: jnp.ndarray, n: int, shape,
 # fused Eq. 9 update
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("zeta", "noise_scale", "interpret"))
-def fused_update(theta, vbar, v, noise, zeta: float, noise_scale: float,
-                 interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("zeta", "noise_scale"))
+def fused_update(theta, vbar, v, noise, zeta: float, noise_scale: float):
     if theta.size == 0:      # zero-size leaf: a (0,)-grid pallas_call is
         return theta         # ill-formed, and the update is vacuous anyway
     t2, n = _pad_to_2d(theta, TILE_C, TILE_R)
@@ -105,7 +104,7 @@ def fused_update(theta, vbar, v, noise, zeta: float, noise_scale: float,
     v2, _ = _pad_to_2d(v, TILE_C, TILE_R)
     n2, _ = _pad_to_2d(noise, TILE_C, TILE_R)
     out = fused_update_pallas(t2, vb2, v2, n2, zeta, noise_scale,
-                              interpret=interpret)
+                              interpret=interpret_mode())
     return _unpad(out, n, theta.shape)
 
 
@@ -113,8 +112,8 @@ def fused_update(theta, vbar, v, noise, zeta: float, noise_scale: float,
 # QSGD
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("levels", "interpret"))
-def qsgd(x, key, levels: int = 16, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("levels",))
+def qsgd(x, key, levels: int = 16):
     """Bitwise-identical to the ``_qsgd_leaf`` codec stage: eps-included
     norm, uniforms drawn at ``x.shape`` (not the padded tile shape), and
     the codec's ``lower + (u < prob)`` rounding inside the kernel."""
@@ -128,7 +127,7 @@ def qsgd(x, key, levels: int = 16, interpret: bool = True):
                         TILE_C, TILE_R)
     out = qsgd_pallas(x2d, u2d, norm, levels,
                       omega=_qsgd_omega(int(np.prod(x.shape)), levels),
-                      interpret=interpret)
+                      interpret=interpret_mode())
     return _unpad(out, n, x.shape)
 
 
@@ -136,10 +135,9 @@ def qsgd(x, key, levels: int = 16, interpret: bool = True):
 # fused compress-in-update (DESIGN.md §13): delta never materializes
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("ratio", "block_size",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("ratio", "block_size"))
 def fused_delta_pack(theta: jnp.ndarray, v: jnp.ndarray, ratio: float = 0.01,
-                     block_size: int = 1024, interpret: bool = True):
+                     block_size: int = 1024):
     """``block_topk_pack(theta - v.astype(theta.dtype))`` without ever
     writing the dense residual (or a padded copy of it) to HBM.
 
@@ -162,22 +160,23 @@ def fused_delta_pack(theta: jnp.ndarray, v: jnp.ndarray, ratio: float = 0.01,
     if n_head:
         parts.append(delta_pack_pallas(
             tf[:n_head].reshape(-1, block_size),
-            vf[:n_head].reshape(-1, block_size), k, interpret=interpret))
+            vf[:n_head].reshape(-1, block_size), k,
+            interpret=interpret_mode()))
     if n_head < n or not parts:
         tpad = jnp.zeros((tile,), tf.dtype).at[:n - n_head].set(tf[n_head:])
         vpad = jnp.zeros((tile,), vf.dtype).at[:n - n_head].set(vf[n_head:])
         parts.append(delta_pack_pallas(
             tpad.reshape(ROWS_PER_TILE, block_size),
-            vpad.reshape(ROWS_PER_TILE, block_size), k, interpret=interpret))
+            vpad.reshape(ROWS_PER_TILE, block_size), k,
+            interpret=interpret_mode()))
     vals = jnp.concatenate([p[0] for p in parts])[:nb]
     idx = jnp.concatenate([p[1] for p in parts])[:nb].astype(jnp.uint16)
     return vals, idx
 
 
-@functools.partial(jax.jit, static_argnames=("levels", "out_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("levels", "out_dtype"))
 def qsgd_quantize_carrier(x: jnp.ndarray, key, levels: int = 16,
-                          out_dtype=jnp.int8, interpret: bool = True):
+                          out_dtype=jnp.int8):
     """QSGD-quantize a packed ``(nb, k)`` carrier onto the signed integer
     wire grid: returns ``(grid (nb, k) out_dtype, norm () f32)``.
 
@@ -192,25 +191,22 @@ def qsgd_quantize_carrier(x: jnp.ndarray, key, levels: int = 16,
     xp = jnp.pad(x, ((0, nb_pad - nb), (0, 0)))
     up = jnp.pad(u, ((0, nb_pad - nb), (0, 0)))
     grid = grid_quant_pallas(xp, up, norm.reshape(1, 1), levels, out_dtype,
-                             interpret=interpret)
+                             interpret=interpret_mode())
     return grid[:nb], norm
 
 
 # --------------------------------------------------------------------------
-# pytree-level CD-BFL entry points (used when FedConfig.use_pallas)
+# pytree-level entry points
 # --------------------------------------------------------------------------
 
-def tree_block_topk(tree, ratio: float, block_size: int = 1024,
-                    interpret: bool = True):
+def tree_block_topk(tree, ratio: float, block_size: int = 1024):
     return jax.tree.map(
-        lambda x: block_topk(x, ratio=ratio, block_size=block_size,
-                             interpret=interpret), tree)
+        lambda x: block_topk(x, ratio=ratio, block_size=block_size), tree)
 
 
 def tree_fused_update(theta_tree, vbar_tree, v_tree, noise_tree,
-                      zeta: float, noise_scale: float, interpret: bool = True):
+                      zeta: float, noise_scale: float):
     return jax.tree.map(
         lambda t, vb, v, n: fused_update(t, vb, v, n, zeta=zeta,
-                                         noise_scale=noise_scale,
-                                         interpret=interpret),
+                                         noise_scale=noise_scale),
         theta_tree, vbar_tree, v_tree, noise_tree)

@@ -41,7 +41,7 @@ def _qsgd_kernel(x_ref, u_ref, norm_ref, o_ref, *, levels: int,
 
 
 def qsgd_pallas(x, uniform, norm, levels: int, *, omega: float = 0.0,
-                interpret: bool = True):
+                interpret: bool):
     """x/uniform (R, C); norm (1,1) float32."""
     r, c = x.shape
     assert r % TILE_R == 0 and c == TILE_C, (r, c)

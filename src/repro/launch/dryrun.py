@@ -162,7 +162,7 @@ def dryrun_combo(arch_id: str, shape_name: str, *, multi_pod: bool = False,
             sp = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, dt), sp)
         return sp
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if step == "train":
             pspecs = _pspecs()
             pshard = shd.params_shardings(pspecs, mesh, rules)

@@ -21,6 +21,8 @@ import argparse
 import json
 import os
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 DEFAULT_SCENARIOS = ("clean", "gain_drift", "clutter_ramp", "doa_miscal",
                      "snr_degradation", "room_geometry", "node_hetero")
@@ -60,6 +62,7 @@ def _parse_args():
 
 def main():
     args = _parse_args()
+    enable_compile_cache()
     from repro.core.calibration import render_reliability
     from repro.data.scenarios import list_scenarios
     from repro.eval.matrix import (MatrixSpec, evaluate_params_matrix,

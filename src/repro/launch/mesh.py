@@ -14,15 +14,12 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """Auto axes: GSPMD propagates shardings and the activation hints of
+    ``models/sharding_hints.py`` stay hints, not asserts."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model_axis: int = 1):
-    """Degenerate mesh on the real local devices (CPU tests/examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_fed_mesh(num_shards: int = 0, fed_axis: str = "fed"):
@@ -33,12 +30,16 @@ def make_fed_mesh(num_shards: int = 0, fed_axis: str = "fed"):
     GSPMD-auto path of ``launch/train.py --mesh N`` run on; on CPU, force
     devices first (``repro.launch.xla_flags.force_host_device_count``).
     """
-    n = num_shards or len(jax.devices())
-    if n > len(jax.devices()):
-        raise ValueError(
-            f"requested {n} shards but only {len(jax.devices())} devices "
-            f"are visible; set XLA_FLAGS=--xla_force_host_platform_device_"
-            f"count={n} before JAX initializes (see repro.launch.xla_flags)")
+    devices = jax.devices()
+    n = num_shards or len(devices)
+    if n > len(devices):
+        hint = (f"on the CPU backend, call repro.launch.xla_flags."
+                f"force_host_device_count({n}) before JAX initializes"
+                if devices[0].platform == "cpu" else
+                f"this host has {len(devices)} {devices[0].platform} "
+                f"device(s): use --mesh {len(devices)} or fewer")
+        raise ValueError(f"requested {n} shards but only {len(devices)} "
+                         f"devices are visible; {hint}")
     return jax.make_mesh((n,), (fed_axis,))
 
 

@@ -19,16 +19,31 @@ for snapshots appearing live (a concurrently running trainer).
     # BMA decode with the sample axis sharded over 8 host devices
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m --trim \
         --mode decode --mesh 8 --samples 8 --requests 16
+
+``--trim`` is for CPU runs only. :func:`main` takes an argument list and
+returns a :class:`ServeRun`, so a caller such as ``chip_smoke.py`` drives
+this exact path and checks the responses.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, List, NamedTuple, Optional, Sequence
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.xla_flags import force_host_device_count
 
 
-def _parse_args():
+class ServeRun(NamedTuple):
+    """What one :func:`main` run served."""
+    responses: List[Any]        # ServeResponse, sorted by request id
+    requests: List[Any]         # the ServeRequests, in submission order
+    bank: Any                   # the posterior bank the engine started from
+    node_axis: Optional[int]    # 1 for (S, K, ...) trainer banks
+    recompiles: int             # compiles after the warmup request
+
+
+def _parse_args(argv: Optional[Sequence[str]] = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="lenet-radar")
     ap.add_argument("--trim", action="store_true", help="use reduced config")
@@ -66,13 +81,14 @@ def _parse_args():
     ap.add_argument("--smoke", action="store_true",
                     help="CI mode: assert zero recompiles after warmup and "
                          "print the response fields")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main():
-    args = _parse_args()
+def main(argv: Optional[Sequence[str]] = None) -> ServeRun:
+    args = _parse_args(argv)
     if args.mesh > 1:
         force_host_device_count(args.mesh)
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -136,6 +152,7 @@ def main():
         pending_steps = []
     lead = jax.tree.leaves(stacked)[0].ndim - jax.tree.leaves(base_ndims)[0]
     node_axis = 1 if lead == 2 else None    # (S, K, ...) trainer banks
+    bank0 = stacked
 
     # -- engine + requests -------------------------------------------------
     if mode == "classify":
@@ -215,6 +232,7 @@ def main():
         assert isinstance(r.abstain, bool)
         print("SMOKE OK: zero recompiles after warmup; response carries "
               "probs/entropy/abstain/latency/bank_version")
+    return ServeRun(resps, reqs, bank0, node_axis, recompiles)
 
 
 if __name__ == "__main__":

@@ -21,20 +21,35 @@ runs in three execution modes:
     PYTHONPATH=src python -m repro.launch.train --arch lenet-radar --trim \
         --nodes 8 --mesh 4 --engine shard --rounds 20
 
-``--trim`` shrinks the model to the reduced config (CPU-budget runs);
-omit it on real hardware. On CPU, ``--mesh N`` forces N host devices via
-XLA_FLAGS — it must therefore run before anything initializes the JAX
-backend (this driver handles that; see ``repro.launch.xla_flags``).
+``--trim`` shrinks the model to the reduced config, for CPU runs only;
+on a TPU the model runs at its published width. On CPU, ``--mesh N``
+forces N host devices — it must therefore run before anything initializes
+the JAX backend (:func:`main` handles that; see ``repro.launch.xla_flags``).
+
+:func:`main` takes an argument list and returns a :class:`TrainRun`, so a
+caller such as ``chip_smoke.py`` drives this exact path and checks what it
+produced.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, List, NamedTuple, Optional, Sequence
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.xla_flags import force_host_device_count
 
 
-def _parse_args():
+class TrainRun(NamedTuple):
+    """What one :func:`main` run leaves behind."""
+    state: Any                  # final FedState (leaves (K, ...))
+    losses: List[float]         # per-round mean loss, every round
+    consensus: List[float]      # per-round consensus error, every round
+    compressor: Any             # the codec the rounds encoded with
+    bank: Any                   # (S, K, ...) posterior samples, or None
+
+
+def _parse_args(argv: Optional[Sequence[str]] = None):
     # jax-free import: topology pulls in numpy + repro.config only
     from repro.core.topology import GRAPHS
 
@@ -194,14 +209,15 @@ def _parse_args():
                          "(lenet pools; see repro.data.scenarios)")
     ap.add_argument("--eval-severity", type=float, default=1.0)
     ap.add_argument("--eval-examples", type=int, default=128)
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main():
+def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
     # flags first: --mesh N needs N host devices before JAX initializes
-    args = _parse_args()
+    args = _parse_args(argv)
     if args.mesh > 1:
         force_host_device_count(args.mesh)
+    enable_compile_cache()
     if args.engine == "shard" and args.mesh < 2:
         raise SystemExit("--engine shard needs --mesh >= 2")
     if args.nodes % max(args.mesh, 1):
@@ -479,6 +495,9 @@ def main():
 
     segment = args.eval_every if args.eval_every > 0 else args.rounds
     done = 0
+    all_losses: List[float] = []
+    all_cons: List[float] = []
+    stacked_bank = None
     while done < args.rounds:
         n = min(segment, args.rounds - done)
         subsegs = (list(refresher.segments(done, n))
@@ -486,9 +505,11 @@ def main():
         for s0, m in subsegs:
             if refresher is not None:
                 refresher.refresh(engine, s0)
-            state, key, bank_state, losses, _ = engine.run(
+            state, key, bank_state, losses, cons = engine.run(
                 state, key, bank_state, m, t0=s0,
                 log_every=args.log_every, log_cb=log_cb)
+            all_losses.extend(losses)
+            all_cons.extend(cons)
         done += n
         stacked_bank = bank_stacked()
         if eval_engine is not None:
@@ -559,6 +580,7 @@ def main():
         path = save_checkpoint(args.ckpt_dir, args.rounds, state.params,
                                metadata={"arch": cfg.name, "fed": vars(args)})
         print("saved", path)
+    return TrainRun(state, all_losses, all_cons, comp, stacked_bank)
 
 
 if __name__ == "__main__":

@@ -1,56 +1,37 @@
-"""Safe manipulation of XLA_FLAGS for forced host device counts.
+"""Forcing the CPU backend's device count for multi-device runs on a host.
 
-The CPU backend fixes its device count the moment JAX initializes, so
-``--xla_force_host_platform_device_count`` must land in the environment
-before that — and must *never* be mutated by a mere import: the dry-run
-entry point used to set it at module level, which meant importing a dryrun
-helper from a test (or from the shard engine) could silently reconfigure —
-or fail to reconfigure — the process's backend. Entry points call
-:func:`force_host_device_count` under their ``__main__`` guard instead.
+The CPU backend fixes its device count the moment JAX initializes, so the
+count must be set before that — and never by a mere import: importing a
+dry-run helper from a test (or from the shard engine) must not reconfigure
+the process's backend. Entry points call :func:`force_host_device_count`
+under their ``__main__`` guard instead. The setting is JAX's public
+``jax_num_cpu_devices`` option; it touches only the CPU backend, so on a
+TPU host it changes nothing.
 
 This module must stay importable without importing JAX.
 """
 from __future__ import annotations
 
-import os
-import sys
 import warnings
-
-_FLAG = "--xla_force_host_platform_device_count"
-
-
-def backend_initialized() -> bool:
-    """True once JAX has instantiated a backend (device count is locked)."""
-    if "jax" not in sys.modules:
-        return False
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge._backends)
-    except Exception:
-        # private API moved: be conservative and assume initialized
-        return True
 
 
 def force_host_device_count(n: int) -> bool:
-    """Merge ``--xla_force_host_platform_device_count=n`` into XLA_FLAGS.
+    """Give the CPU backend ``n`` devices; True when the count was set.
 
-    Returns True when the flag was (re)set. No-ops with a warning when the
-    backend is already initialized — the count cannot change anymore, and
-    clobbering XLA_FLAGS at that point would only confuse later readers.
-    Other flags already present in XLA_FLAGS are preserved.
+    Once a backend exists the count cannot change anymore: JAX refuses
+    the update, and this returns False, warning when the visible device
+    count differs from ``n``.
     """
-    if backend_initialized():
-        import jax
+    import jax
+    try:
+        jax.config.update("jax_num_cpu_devices", n)
+        return True
+    except RuntimeError:
         have = len(jax.devices())
         if have != n:
             warnings.warn(
                 f"JAX backend already initialized with {have} device(s); "
-                f"cannot force {n} host devices now. Set "
-                f"XLA_FLAGS={_FLAG}={n} before the first jax call.",
+                f"cannot force {n} host devices now. Call "
+                f"force_host_device_count before the first JAX operation.",
                 stacklevel=2)
         return False
-    keep = [f for f in os.environ.get("XLA_FLAGS", "").split()
-            if not f.startswith(_FLAG)]
-    keep.append(f"{_FLAG}={n}")
-    os.environ["XLA_FLAGS"] = " ".join(keep)
-    return True

@@ -4,8 +4,8 @@ GSPMD loses the batch sharding of q/k/v when they are restacked as scan
 inputs for the chunked attention/recurrence paths (observed in the qwen
 train_4k dry-run: attention dots executed with the FULL global batch per
 device — a 16× compute waste). ``hint()`` re-anchors the intended sharding
-with ``with_sharding_constraint``; outside a mesh context (unit tests, CPU
-examples) it is an identity.
+with ``with_sharding_constraint``; outside a ``jax.set_mesh`` context (unit
+tests, CPU examples) it is an identity.
 
 Axis names are filtered against the active mesh, and dims that don't divide
 fall back to replicated, so the same model code works on 1 CPU device, the
@@ -49,14 +49,9 @@ class reserve_axes:
 
 
 def _current_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is None or m.empty:
-            return None
-        return m
-    except Exception:
-        return None
+    """The mesh installed by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def hint(x, *spec: AxisSpec):
@@ -82,10 +77,7 @@ def hint(x, *spec: AxisSpec):
         total = int(np.prod([m.shape[a] for a in axes]))
         clean.append((axes if len(axes) > 1 else axes[0])
                      if (dim % total == 0 and dim >= total) else None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*clean))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*clean))
 
 
 def hint_batch(x):

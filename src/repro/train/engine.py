@@ -305,18 +305,6 @@ class HostRoundEngine:
         return state, key, bank_state, losses, cons
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions (experimental before jax 0.6)."""
-    try:
-        from jax import shard_map as _sm            # jax >= 0.6
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except (ImportError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
 class ShardRoundEngine:
     """Scan-fused super-rounds with the node axis sharded over a mesh axis.
 
@@ -437,10 +425,10 @@ class ShardRoundEngine:
                                     carry, ts)
 
             def chunk(data_sizes, carry, t0):
-                return _shard_map(
-                    local_chunk, self.mesh,
+                return jax.shard_map(
+                    local_chunk, mesh=self.mesh,
                     in_specs=(data_specs, carry_specs, P()),
-                    out_specs=(carry_specs, metric_specs),
+                    out_specs=(carry_specs, metric_specs), check_vma=False,
                 )(data_sizes, carry, t0)
 
             self._chunk_fns[length] = jax.jit(chunk, donate_argnums=(1,))

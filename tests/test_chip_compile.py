@@ -1,0 +1,90 @@
+"""The encode-path Pallas kernels compile for a TPU v5e (``interpret=False``).
+
+Each case lowers one kernel at a real leaf width and compiles it for one
+chip of a described ``v5e:2x2`` topology: the TPU compiler runs here with
+no chip attached and refuses what Mosaic cannot lower, which interpret mode
+never shows. Nothing executes, so these say nothing about results or times.
+
+The topology is described inside a module fixture — never at import, in a
+``skipif`` or in ``parametrize`` — so every test worker collects the same
+cases and only the worker that runs this file loads the TPU library. The
+persistent compilation cache is off around the compiles: an entry written
+for a described chip cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.block_topk import ROWS_PER_TILE, block_topk_pallas
+from repro.kernels.fused_compress import delta_pack_pallas, grid_quant_pallas
+from repro.kernels.pack import pack_topk_pallas, unpack_topk_pallas
+from repro.kernels.qsgd import TILE_C, TILE_R, qsgd_pallas
+
+BLOCK, RATIO, LEVELS = 1024, 0.01, 16
+K = 11                                   # ceil(RATIO * BLOCK)
+LEAVES = {
+    "lenet_fc1": 11712 * 220,            # lenet-radar fc1 kernel, 2,576,640
+    "smollm_embed": 49152 * 576,         # smollm-135m embed_tokens
+}
+
+
+def _round_up(n, m):
+    return -(-n // m) * m
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:               # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _cases(n):
+    """kernel name -> (fn, argument shapes/dtypes) for an n-element leaf."""
+    nb = _round_up(-(-n // BLOCK), ROWS_PER_TILE)
+    rows = _round_up(-(-n // TILE_C), TILE_R)
+    blocks, carrier, f32 = (nb, BLOCK), (nb, K), jnp.float32
+    return {
+        "block_topk": (lambda x: block_topk_pallas(x, K, interpret=False),
+                       [(blocks, f32)]),
+        "pack": (lambda x: pack_topk_pallas(x, K, interpret=False),
+                 [(blocks, f32)]),
+        "unpack": (lambda v, i: unpack_topk_pallas(v, i, BLOCK,
+                                                   interpret=False),
+                   [(carrier, f32), (carrier, jnp.int32)]),
+        "delta_pack": (lambda t, v: delta_pack_pallas(t, v, K,
+                                                      interpret=False),
+                       [(blocks, f32), (blocks, f32)]),
+        "grid_quant": (lambda x, u, s: grid_quant_pallas(
+            x, u, s, LEVELS, jnp.int8, interpret=False),
+            [(carrier, f32), (carrier, f32), ((1, 1), f32)]),
+        "qsgd": (lambda x, u, s: qsgd_pallas(x, u, s, LEVELS,
+                                             interpret=False),
+                 [((rows, TILE_C), f32), ((rows, TILE_C), f32),
+                  ((1, 1), f32)]),
+    }
+
+
+@pytest.mark.parametrize("leaf", sorted(LEAVES))
+@pytest.mark.parametrize("kernel", ["block_topk", "pack", "unpack",
+                                    "delta_pack", "grid_quant", "qsgd"])
+def test_kernel_compiles_for_v5e(one_chip, kernel, leaf):
+    fn, arg_specs = _cases(LEAVES[leaf])[kernel]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in arg_specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels import ops, ref
+from repro.kernels import interpret_mode, ops, ref
 from repro.kernels.block_topk import block_topk_pallas
 from repro.kernels.fused_update import fused_update_pallas
 from repro.kernels.qsgd import qsgd_pallas
@@ -14,6 +14,14 @@ KEY = jax.random.PRNGKey(0)
 
 SHAPES = [(1024,), (8, 1024), (3, 1000, 7), (4097,), (128, 130)]
 DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    assert interpret_mode() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert interpret_mode() is True
 
 
 # --------------------------------------------------------------------------

@@ -60,7 +60,6 @@ def _tree(k=K):
 def _run_shard_mixer(omega, cfg, s, tree, key=None):
     """Execute the shard mixer inside shard_map on an S-shard mesh."""
     from jax.sharding import PartitionSpec as P
-    from repro.train.engine import _shard_map
     ctx = ShardContext("fed", s)
     mixer, stats = make_shard_mixer(omega, ctx, config=cfg)
     specs = jax.tree.map(lambda _: P("fed"), tree)
@@ -68,8 +67,8 @@ def _run_shard_mixer(omega, cfg, s, tree, key=None):
     def local(t, k):
         return mixer(t, k)
 
-    fn = _shard_map(local, _mesh(s), in_specs=(specs, P()),
-                    out_specs=specs)
+    fn = jax.shard_map(local, mesh=_mesh(s), in_specs=(specs, P()),
+                       out_specs=specs, check_vma=False)
     return jax.jit(fn)(tree, key if key is not None
                        else jax.random.PRNGKey(1)), stats
 
