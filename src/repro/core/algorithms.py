@@ -221,19 +221,24 @@ def _compress_exchange(compressor, theta, v, key, node_ids,
     keys = _node_keys_for(key, node_ids)
     local_k = node_ids.shape[0]
     if hasattr(compressor, "encode_pair"):
-        payload = jax.vmap(compressor.encode_pair)(theta, v, keys)
+        with jax.named_scope("encode"):
+            payload = jax.vmap(compressor.encode_pair)(theta, v, keys)
         wire = jnp.float32(payload.measured_bytes() / local_k)
-        if transport is None:
-            delta = jax.vmap(compressor.decode)(payload)
-            return delta, delta, wire, None
-        kloss = jax.random.fold_in(key, TRANSPORT_SALT)
-        tkeys = _node_keys_for(kloss, node_ids)
-        delta_full, delta_del, tx = jax.vmap(
-            partial(transport.deliver, compressor))(payload, tkeys, node_ids)
+        with jax.named_scope("decode"):
+            if transport is None:
+                delta = jax.vmap(compressor.decode)(payload)
+                return delta, delta, wire, None
+            kloss = jax.random.fold_in(key, TRANSPORT_SALT)
+            tkeys = _node_keys_for(kloss, node_ids)
+            delta_full, delta_del, tx = jax.vmap(
+                partial(transport.deliver, compressor))(payload, tkeys,
+                                                        node_ids)
         delta_v = delta_del if transport.error_feedback else delta_full
         return delta_v, delta_del, wire, tx
-    residual = jax.tree.map(lambda t, vv: t - vv.astype(t.dtype), theta, v)
-    delta = jax.vmap(compressor)(residual, keys)
+    # a dense-masked Compressor encodes and decodes in one call
+    with jax.named_scope("encode"):
+        residual = jax.tree.map(lambda t, vv: t - vv.astype(t.dtype), theta, v)
+        delta = jax.vmap(compressor)(residual, keys)
     wire = compressor.wire_bytes(jax.tree.map(lambda x: x[0], residual))
     return delta, delta, jnp.float32(wire), None
 
@@ -388,7 +393,8 @@ def make_cdbfl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
             prior_weight=prior_weight, data_scale=data_scale,
             num_steps_static=L,
         )
-        theta_L, losses = jax.vmap(local)(state.params, batches, node_keys)
+        with jax.named_scope("local_step"):
+            theta_L, losses = jax.vmap(local)(state.params, batches, node_keys)
 
         # -- Eq. 6: compressed residual vs control sequence ------------------
         # encode -> wire payload -> decode: the packed (values, indices)
@@ -403,23 +409,26 @@ def make_cdbfl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
         # under a lossy transport, v absorbs the *delivered* delta (error
         # feedback: lost frames stay in the next residual); delta below is
         # always the delivered one — it is what the neighbors mix in.
-        v_new = jax.tree.map(lambda v, d: (v + d.astype(v.dtype)), state.v,
-                             delta_v)
-        mixed = mixer(delta, kmix) if p_full is None else mixer(
-            delta, kmix, p_full)
-        v_bar_new = jax.tree.map(lambda vb, m: (vb + m.astype(vb.dtype)),
-                                 state.v_bar, mixed)
+        with jax.named_scope("mix"):
+            v_new = jax.tree.map(lambda v, d: (v + d.astype(v.dtype)),
+                                 state.v, delta_v)
+            mixed = mixer(delta, kmix) if p_full is None else mixer(
+                delta, kmix, p_full)
+            v_bar_new = jax.tree.map(lambda vb, m: (vb + m.astype(vb.dtype)),
+                                     state.v_bar, mixed)
 
         # -- Eq. 9: consensus correction + Langevin noise --------------------
-        noise = _langevin_noise(knoise, theta_L, eta, fed_cfg.temperature, ids)
-        params_new = jax.tree.map(
-            lambda t, vb, v, n: (
-                t.astype(jnp.float32)
-                + zeta * (vb.astype(jnp.float32) - v.astype(jnp.float32))
-                + n
-            ).astype(t.dtype),
-            theta_L, v_bar_new, v_new, noise,
-        )
+        with jax.named_scope("langevin_noise"):
+            noise = _langevin_noise(knoise, theta_L, eta, fed_cfg.temperature,
+                                    ids)
+            params_new = jax.tree.map(
+                lambda t, vb, v, n: (
+                    t.astype(jnp.float32)
+                    + zeta * (vb.astype(jnp.float32) - v.astype(jnp.float32))
+                    + n
+                ).astype(t.dtype),
+                theta_L, v_bar_new, v_new, noise,
+            )
         if p_local is not None:
             # barrier-free: a skipped round leaves the node's whole state
             # stale — nothing sent (tx masked below), nothing absorbed
@@ -429,21 +438,23 @@ def make_cdbfl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
             params_new = _participation_freeze(p_local, params_new,
                                                state.params)
 
-        txm = _reduce_transport(_mask_transport(tx, p_local), shard_ctx, K)
-        metrics = RoundMetrics(
-            loss=losses,
-            consensus_error=_consensus_error(params_new, shard_ctx, K) / K,
-            delta_norm=_sq_norm(delta, shard_ctx) / K,
-            wire_bytes=wire,
-            cross_bytes=_cross_bytes(mix_stats, delta, ids.shape[0]),
-            offered_bytes=txm.offered,
-            delivered_bytes=txm.delivered,
-            airtime_s=txm.airtime_s,
-            energy_j=txm.energy_j,
-            retransmits=txm.retransmits,
-            abandoned_bytes=txm.abandoned,
-            participation=p_full if p_full is not None else 1.0,
-        )
+        with jax.named_scope("round_metrics"):
+            txm = _reduce_transport(_mask_transport(tx, p_local), shard_ctx,
+                                    K)
+            metrics = RoundMetrics(
+                loss=losses,
+                consensus_error=_consensus_error(params_new, shard_ctx, K) / K,
+                delta_norm=_sq_norm(delta, shard_ctx) / K,
+                wire_bytes=wire,
+                cross_bytes=_cross_bytes(mix_stats, delta, ids.shape[0]),
+                offered_bytes=txm.offered,
+                delivered_bytes=txm.delivered,
+                airtime_s=txm.airtime_s,
+                energy_j=txm.energy_j,
+                retransmits=txm.retransmits,
+                abandoned_bytes=txm.abandoned,
+                participation=p_full if p_full is not None else 1.0,
+            )
         new_state = FedState(
             params=params_new, v=v_new, v_bar=v_bar_new,
             opt_state=state.opt_state, key=state.key, round=state.round + 1,
@@ -505,45 +516,51 @@ def make_dsgld_round(loss_fn: LossFn, fed_cfg, omega, data_scale: float = 1.0,
         node_keys = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
             state.key, state.round
         )
-        losses, grads = jax.vmap(node_grad)(state.params, batch0, node_keys)
+        with jax.named_scope("local_step"):
+            losses, grads = jax.vmap(node_grad)(state.params, batch0,
+                                                node_keys)
 
         # full θ exchange (uncompressed)
-        mixed = mixer(state.params, kmix) if p_full is None else mixer(
-            state.params, kmix, p_full)
-        noise = _langevin_noise(knoise, state.params, eta, fed_cfg.temperature,
-                                ids)
-        params_new = jax.tree.map(
-            lambda m, g, n: (
-                m.astype(jnp.float32) - eta * g.astype(jnp.float32) + n
-            ).astype(m.dtype),
-            mixed, grads, noise,
-        )
+        with jax.named_scope("mix"):
+            mixed = mixer(state.params, kmix) if p_full is None else mixer(
+                state.params, kmix, p_full)
+        with jax.named_scope("langevin_noise"):
+            noise = _langevin_noise(knoise, state.params, eta,
+                                    fed_cfg.temperature, ids)
+            params_new = jax.tree.map(
+                lambda m, g, n: (
+                    m.astype(jnp.float32) - eta * g.astype(jnp.float32) + n
+                ).astype(m.dtype),
+                mixed, grads, noise,
+            )
         if p_local is not None:
             params_new = _participation_freeze(p_local, params_new,
                                                state.params)
         dense_bytes = tree_count(state.params) // ids.shape[0] * 4
-        txm = (transport.account_dense(dense_bytes)
-               if transport is not None else TransportMetrics.zero())
-        if p_full is not None:
-            # static per-node accounting × the realized participation rate:
-            # a node that skipped the round never offered its dense θ
-            rate = jnp.mean(p_full)
-            txm = TransportMetrics(*(jnp.asarray(f) * rate for f in txm))
-        metrics = RoundMetrics(
-            loss=losses[:, None],
-            consensus_error=_consensus_error(params_new, shard_ctx, K) / K,
-            delta_norm=_sq_norm(state.params, shard_ctx) / K,
-            # uncompressed θ exchange: dense fp32 payload per node
-            wire_bytes=jnp.float32(dense_bytes),
-            cross_bytes=_cross_bytes(mix_stats, state.params, ids.shape[0]),
-            offered_bytes=txm.offered,
-            delivered_bytes=txm.delivered,
-            airtime_s=txm.airtime_s,
-            energy_j=txm.energy_j,
-            retransmits=txm.retransmits,
-            abandoned_bytes=txm.abandoned,
-            participation=p_full if p_full is not None else 1.0,
-        )
+        with jax.named_scope("round_metrics"):
+            txm = (transport.account_dense(dense_bytes)
+                   if transport is not None else TransportMetrics.zero())
+            if p_full is not None:
+                # static per-node accounting × the realized participation
+                # rate: a node that skipped the round never offered its θ
+                rate = jnp.mean(p_full)
+                txm = TransportMetrics(*(jnp.asarray(f) * rate for f in txm))
+            metrics = RoundMetrics(
+                loss=losses[:, None],
+                consensus_error=_consensus_error(params_new, shard_ctx, K) / K,
+                delta_norm=_sq_norm(state.params, shard_ctx) / K,
+                # uncompressed θ exchange: dense fp32 payload per node
+                wire_bytes=jnp.float32(dense_bytes),
+                cross_bytes=_cross_bytes(mix_stats, state.params,
+                                         ids.shape[0]),
+                offered_bytes=txm.offered,
+                delivered_bytes=txm.delivered,
+                airtime_s=txm.airtime_s,
+                energy_j=txm.energy_j,
+                retransmits=txm.retransmits,
+                abandoned_bytes=txm.abandoned,
+                participation=p_full if p_full is not None else 1.0,
+            )
         return (
             FedState(params_new, state.v, state.v_bar, state.opt_state,
                      state.key, state.round + 1),
@@ -589,16 +606,18 @@ def make_cffl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
             _local_sgd, loss_fn=loss_fn, eta=eta,
             prior_weight=0.0, data_scale=data_scale, num_steps_static=L,
         )
-        theta_L, losses = jax.vmap(local)(state.params, batches, node_keys)
+        with jax.named_scope("local_step"):
+            theta_L, losses = jax.vmap(local)(state.params, batches, node_keys)
 
         delta_v, delta, wire, tx = _compress_exchange(
             compressor, theta_L, state.v, kq, ids, transport)
-        v_new = jax.tree.map(lambda v, d: (v + d.astype(v.dtype)), state.v,
-                             delta_v)
-        mixed = mixer(delta, kmix) if p_full is None else mixer(
-            delta, kmix, p_full)
-        v_bar_new = jax.tree.map(lambda vb, m: (vb + m.astype(vb.dtype)),
-                                 state.v_bar, mixed)
+        with jax.named_scope("mix"):
+            v_new = jax.tree.map(lambda v, d: (v + d.astype(v.dtype)),
+                                 state.v, delta_v)
+            mixed = mixer(delta, kmix) if p_full is None else mixer(
+                delta, kmix, p_full)
+            v_bar_new = jax.tree.map(lambda vb, m: (vb + m.astype(vb.dtype)),
+                                     state.v_bar, mixed)
         params_new = jax.tree.map(
             lambda t, vb, v: (
                 t.astype(jnp.float32)
@@ -611,21 +630,23 @@ def make_cffl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
             v_bar_new = _participation_freeze(p_local, v_bar_new, state.v_bar)
             params_new = _participation_freeze(p_local, params_new,
                                                state.params)
-        txm = _reduce_transport(_mask_transport(tx, p_local), shard_ctx, K)
-        metrics = RoundMetrics(
-            loss=losses,
-            consensus_error=_consensus_error(params_new, shard_ctx, K) / K,
-            delta_norm=_sq_norm(delta, shard_ctx) / K,
-            wire_bytes=wire,
-            cross_bytes=_cross_bytes(mix_stats, delta, ids.shape[0]),
-            offered_bytes=txm.offered,
-            delivered_bytes=txm.delivered,
-            airtime_s=txm.airtime_s,
-            energy_j=txm.energy_j,
-            retransmits=txm.retransmits,
-            abandoned_bytes=txm.abandoned,
-            participation=p_full if p_full is not None else 1.0,
-        )
+        with jax.named_scope("round_metrics"):
+            txm = _reduce_transport(_mask_transport(tx, p_local), shard_ctx,
+                                    K)
+            metrics = RoundMetrics(
+                loss=losses,
+                consensus_error=_consensus_error(params_new, shard_ctx, K) / K,
+                delta_norm=_sq_norm(delta, shard_ctx) / K,
+                wire_bytes=wire,
+                cross_bytes=_cross_bytes(mix_stats, delta, ids.shape[0]),
+                offered_bytes=txm.offered,
+                delivered_bytes=txm.delivered,
+                airtime_s=txm.airtime_s,
+                energy_j=txm.energy_j,
+                retransmits=txm.retransmits,
+                abandoned_bytes=txm.abandoned,
+                participation=p_full if p_full is not None else 1.0,
+            )
         return (
             FedState(params_new, v_new, v_bar_new, state.opt_state,
                      state.key, state.round + 1),

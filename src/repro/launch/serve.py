@@ -222,6 +222,9 @@ def main(argv: Optional[Sequence[str]] = None) -> ServeRun:
           f"entropy_mean={np.mean([r.entropy for r in resps]):.3f} "
           f"compiles={eng.compile_count()} recompiles={recompiles} "
           f"bank_version={eng.bank_version}")
+    print(f"serve: slot_occupancy={st['slot_occupancy']:.3f} "
+          f"queued_share={st['queued_share']:.3f} "
+          f"queue_ms_mean={st['queue_ms_mean']:.2f}")
     if args.smoke:
         assert recompiles == 0, \
             f"{recompiles} recompiles after warmup (continuous batching " \

@@ -459,10 +459,17 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
         else:
             eval_engine = ScanEvalEngine(apply_fn)
 
-    t0 = time.time()
-    log_cb = lambda t, loss, cons: print(
-        f"round {t:4d} loss={loss:.4f} consensus={cons:.3e} "
-        f"({(time.time()-t0)/max(t, 1):.2f}s/round)")
+    last_time, last_round = time.perf_counter(), 0
+
+    def log_cb(t, loss, cons):
+        """One line per log interval; its s/round covers that interval
+        only, so compilation shows in the first line alone."""
+        nonlocal last_time, last_round
+        now = time.perf_counter()
+        per_round = (now - last_time) / max(t - last_round, 1)
+        last_time, last_round = now, t
+        print(f"round {t:4d} loss={loss:.4f} consensus={cons:.3e} "
+              f"({per_round:.2f}s/round)")
     key = jax.random.fold_in(key, 1)
 
     def bank_stacked():

@@ -50,6 +50,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.config import ServeConfig
 from repro.core.posterior import (BankPredictor, place_ensemble,
@@ -86,16 +87,47 @@ class ServeRequest:
 class ServeResponse:
     """The API response: prediction + uncertainty + the abstain gate.
 
-    Deterministic given the installed bank and the request bits.
+    Deterministic given the installed bank and the request bits. The
+    timing fields place the request on the engine's step counter:
+    ``admit_step`` and ``done_step`` are the ``step`` arguments of the
+    ``serve.step`` trace spans that admitted and finished it.
     """
     request_id: int
     probs: np.ndarray              # (C,) BMA predictive distribution
     entropy: float                 # nats; decode: mean over emitted tokens
     abstain: bool                  # entropy gate: route to a human
     bank_version: int              # posterior snapshot that finished this
-    latency_s: float
+    latency_s: float               # submit to retire
+    queue_s: float                 # submit to admit into a slot
+    queued_steps: int              # steps run between submit and admit
+    admit_step: int                # index of the step that admitted it
+    done_step: int                 # index of the step that finished it
     tokens: Optional[np.ndarray] = None          # decode: (T,) int32
     token_entropy: Optional[np.ndarray] = None   # decode: (T,) f32
+
+
+class _LatencyHistogram:
+    """Latency percentiles from a fixed set of log-spaced buckets (16 per
+    doubling from 1 us, so a percentile is read to about 2%): the memory
+    stays the same however many requests the engine serves."""
+
+    EDGES = 1e-6 * 2.0 ** (np.arange(16 * 34) / 16.0)
+
+    def __init__(self):
+        self.counts = np.zeros(len(self.EDGES) + 1, np.int64)
+
+    def add(self, seconds: float) -> None:
+        self.counts[np.searchsorted(self.EDGES, seconds)] += 1
+
+    def percentile(self, q: float) -> float:
+        """The ``q``-th percentile in seconds (geometric bucket middle)."""
+        n = int(self.counts.sum())
+        if not n:
+            return 0.0
+        i = int(np.searchsorted(np.cumsum(self.counts), q / 100.0 * n))
+        lo = self.EDGES[max(i - 1, 0)]
+        hi = self.EDGES[min(i, len(self.EDGES) - 1)]
+        return float(np.sqrt(lo * hi))
 
 
 class ServingEngine:
@@ -106,6 +138,15 @@ class ServingEngine:
     whole table, and retires finished slots into responses. ``drain``
     steps until idle; ``run`` is submit-all-then-drain.
 
+    Each step is a ``serve.step`` span in a running ``jax.profiler`` trace
+    (arguments: the step index and the slots it runs occupied), split into
+    ``serve.admit``, ``serve.predict``, ``serve.fetch`` and
+    ``serve.retire``; without a profiler session the spans cost nothing.
+    ``steps`` counts the steps that ran a kernel and ``slot_steps`` the
+    occupied slots summed over them; ``stats()`` gives the share of slots
+    occupied, the share of served requests that waited at least one step
+    for a slot, and their mean submit-to-admit time.
+
     Purity: the classification path reproduces the eval engine's probabilities bitwise (``serve_vs_eval_bitwise``, exact-gated in ``bench_serve``).
     """
 
@@ -115,22 +156,46 @@ class ServingEngine:
         self.slot_req: List[Optional[int]] = [None] * cfg.slots
         self.bank_version = 0
         self.steps = 0
+        self.slot_steps = 0
         self._next_id = 0
-        self._submit_t: Dict[int, float] = {}
-        self._latencies: List[float] = []
+        # per pending request: (submit time, steps at submit), then at
+        # admit (submit time, queue_s, queued_steps, admit step)
+        self._submitted: Dict[int, Tuple[float, int]] = {}
+        self._admitted: Dict[int, Tuple[float, float, int, int]] = {}
+        self._latency = _LatencyHistogram()
         self._served = 0
         self._abstained = 0
+        self._queued = 0                 # served requests that waited a step
+        self._queue_s = 0.0              # submit-to-admit seconds, summed
 
     # -- request lifecycle -------------------------------------------------
     def submit(self, req: ServeRequest) -> int:
         rid = self._next_id
         self._next_id += 1
-        self._submit_t[rid] = time.perf_counter()
+        self._submitted[rid] = (time.perf_counter(), self.steps)
         self.queue.append((rid, req))
         return rid
 
     def pending(self) -> int:
         return len(self.queue) + sum(r is not None for r in self.slot_req)
+
+    def _occupied_after_admit(self) -> int:
+        """Slots the coming step runs occupied: those in flight plus the
+        queued requests that fit into the free ones."""
+        busy = sum(r is not None for r in self.slot_req)
+        return busy + min(self.cfg.slots - busy, len(self.queue))
+
+    def _pop_queued(self) -> Tuple[int, ServeRequest]:
+        """The next queued request, stamped as admitted by this step."""
+        rid, req = self.queue.popleft()
+        t_sub, steps_at_submit = self._submitted.pop(rid)
+        self._admitted[rid] = (t_sub, time.perf_counter() - t_sub,
+                               self.steps - steps_at_submit, self.steps)
+        return rid, req
+
+    def _count_step(self, occupied: int) -> None:
+        self.steps += 1
+        self.slot_steps += occupied
 
     def step(self) -> List[ServeResponse]:
         raise NotImplementedError
@@ -151,29 +216,41 @@ class ServingEngine:
                  **kw) -> ServeResponse:
         abstain = bool(abstain_mask(np.float32(entropy),
                                     self.cfg.entropy_threshold))
-        lat = time.perf_counter() - self._submit_t.pop(rid)
-        self._latencies.append(lat)
+        t_sub, queue_s, queued_steps, admit_step = self._admitted.pop(rid)
+        lat = time.perf_counter() - t_sub
+        self._latency.add(lat)
         self._served += 1
         self._abstained += int(abstain)
+        self._queued += int(queued_steps > 0)
+        self._queue_s += queue_s
         return ServeResponse(request_id=rid, probs=probs,
                              entropy=float(entropy), abstain=abstain,
                              bank_version=self.bank_version,
-                             latency_s=lat, **kw)
+                             latency_s=lat, queue_s=queue_s,
+                             queued_steps=queued_steps,
+                             admit_step=admit_step,
+                             done_step=self.steps - 1, **kw)
 
     # -- accounting --------------------------------------------------------
     def compile_count(self) -> int:
         raise NotImplementedError
 
     def stats(self) -> Dict[str, float]:
-        lat = np.asarray(self._latencies, np.float64)
         return {
             "served": float(self._served),
             "abstained": float(self._abstained),
             "abstain_rate": (self._abstained / self._served
                              if self._served else 0.0),
             "steps": float(self.steps),
-            "p50_ms": float(np.percentile(lat, 50) * 1e3) if lat.size else 0.0,
-            "p99_ms": float(np.percentile(lat, 99) * 1e3) if lat.size else 0.0,
+            "slot_steps": float(self.slot_steps),
+            "slot_occupancy": (self.slot_steps / (self.steps * self.cfg.slots)
+                               if self.steps else 0.0),
+            "queued_share": (self._queued / self._served
+                             if self._served else 0.0),
+            "queue_ms_mean": (1e3 * self._queue_s / self._served
+                              if self._served else 0.0),
+            "p50_ms": self._latency.percentile(50) * 1e3,
+            "p99_ms": self._latency.percentile(99) * 1e3,
         }
 
 
@@ -213,24 +290,31 @@ class ClassifyEngine(ServingEngine):
         return self.predictor.num_samples()
 
     def step(self) -> List[ServeResponse]:
-        for i in range(self.cfg.slots):
-            if self.slot_req[i] is None and self.queue:
-                rid, req = self.queue.popleft()
-                self._xs = self._write(self._xs, jnp.asarray(req.x), i)
-                self.slot_req[i] = rid
-        if not any(r is not None for r in self.slot_req):
+        occupied = self._occupied_after_admit()
+        if not occupied:
             return []
-        probs, ent = self.predictor.predict({"x": self._xs})
-        probs = np.asarray(probs, np.float32)
-        ent = np.asarray(ent, np.float32)
-        self.steps += 1
-        done = []
-        for i in range(self.cfg.slots):
-            rid = self.slot_req[i]
-            if rid is None:
-                continue
-            done.append(self._respond(rid, probs[i], float(ent[i])))
-            self.slot_req[i] = None
+        with TraceAnnotation("serve.step", step=self.steps,
+                             occupied=occupied):
+            with TraceAnnotation("serve.admit"):
+                for i in range(self.cfg.slots):
+                    if self.slot_req[i] is None and self.queue:
+                        rid, req = self._pop_queued()
+                        self._xs = self._write(self._xs, jnp.asarray(req.x), i)
+                        self.slot_req[i] = rid
+            with TraceAnnotation("serve.predict"):
+                probs, ent = self.predictor.predict({"x": self._xs})
+            with TraceAnnotation("serve.fetch"):
+                probs = np.asarray(probs, np.float32)
+                ent = np.asarray(ent, np.float32)
+            self._count_step(occupied)
+            done = []
+            with TraceAnnotation("serve.retire"):
+                for i in range(self.cfg.slots):
+                    rid = self.slot_req[i]
+                    if rid is None:
+                        continue
+                    done.append(self._respond(rid, probs[i], float(ent[i])))
+                    self.slot_req[i] = None
         return done
 
     def compile_count(self) -> int:
@@ -395,44 +479,51 @@ class DecodeEngine(ServingEngine):
     def step(self) -> List[ServeResponse]:
         if self._stacked is None:
             raise ValueError("no bank installed; call install_bank(stacked)")
-        for i in range(self.cfg.slots):
-            if self.slot_req[i] is None and self.queue:
-                rid, req = self.queue.popleft()
-                (self._caches, self._tokens, self._pos,
-                 self._keys) = self._admit_fn(
-                    self._caches, self._tokens, self._pos, self._keys,
-                    i, req.prompt_token, req.seed)
-                self.slot_req[i] = rid
-                self.slot_left[i] = req.max_new_tokens or \
-                    self.cfg.max_new_tokens
-                self._slot_toks[rid] = []
-                self._slot_ents[rid] = []
-        if not any(r is not None for r in self.slot_req):
+        occupied = self._occupied_after_admit()
+        if not occupied:
             return []
-        (self._caches, self._tokens, self._pos, probs,
-         ent) = self._step_fn(self._stacked, self._caches, self._tokens,
-                              self._pos, self._keys)
-        toks = np.asarray(self._tokens[:, 0])
-        ents = np.asarray(ent, np.float32)
-        probs_h = None                       # fetched lazily on retire
-        self.steps += 1
-        done = []
-        for i in range(self.cfg.slots):
-            rid = self.slot_req[i]
-            if rid is None:
-                continue
-            self._slot_toks[rid].append(int(toks[i]))
-            self._slot_ents[rid].append(float(ents[i]))
-            self.slot_left[i] -= 1
-            if self.slot_left[i] == 0:
-                if probs_h is None:
-                    probs_h = np.asarray(probs, np.float32)
-                t = np.asarray(self._slot_toks.pop(rid), np.int32)
-                e = np.asarray(self._slot_ents.pop(rid), np.float32)
-                done.append(self._respond(
-                    rid, probs_h[i], float(e.mean()),
-                    tokens=t, token_entropy=e))
-                self.slot_req[i] = None
+        with TraceAnnotation("serve.step", step=self.steps,
+                             occupied=occupied):
+            with TraceAnnotation("serve.admit"):
+                for i in range(self.cfg.slots):
+                    if self.slot_req[i] is None and self.queue:
+                        rid, req = self._pop_queued()
+                        (self._caches, self._tokens, self._pos,
+                         self._keys) = self._admit_fn(
+                            self._caches, self._tokens, self._pos,
+                            self._keys, i, req.prompt_token, req.seed)
+                        self.slot_req[i] = rid
+                        self.slot_left[i] = req.max_new_tokens or \
+                            self.cfg.max_new_tokens
+                        self._slot_toks[rid] = []
+                        self._slot_ents[rid] = []
+            with TraceAnnotation("serve.predict"):
+                (self._caches, self._tokens, self._pos, probs,
+                 ent) = self._step_fn(self._stacked, self._caches,
+                                      self._tokens, self._pos, self._keys)
+            with TraceAnnotation("serve.fetch"):
+                toks = np.asarray(self._tokens[:, 0])
+                ents = np.asarray(ent, np.float32)
+            probs_h = None                   # fetched lazily on retire
+            self._count_step(occupied)
+            done = []
+            with TraceAnnotation("serve.retire"):
+                for i in range(self.cfg.slots):
+                    rid = self.slot_req[i]
+                    if rid is None:
+                        continue
+                    self._slot_toks[rid].append(int(toks[i]))
+                    self._slot_ents[rid].append(float(ents[i]))
+                    self.slot_left[i] -= 1
+                    if self.slot_left[i] == 0:
+                        if probs_h is None:
+                            probs_h = np.asarray(probs, np.float32)
+                        t = np.asarray(self._slot_toks.pop(rid), np.int32)
+                        e = np.asarray(self._slot_ents.pop(rid), np.float32)
+                        done.append(self._respond(
+                            rid, probs_h[i], float(e.mean()),
+                            tokens=t, token_entropy=e))
+                        self.slot_req[i] = None
         return done
 
     def compile_count(self) -> int:

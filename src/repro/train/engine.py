@@ -30,6 +30,15 @@ from the node's *global* id. Their trajectories (params, metrics,
 posterior banks) therefore coincide — bitwise for the shard engine's
 per-node state — and the equivalence tests in ``tests/test_engine.py`` /
 ``tests/test_shard.py`` pin this down.
+
+Where the time goes is visible in a ``jax.profiler`` trace. Inside a chunk
+each phase runs under a ``jax.named_scope`` that reaches every op's HLO
+``op_name``: ``sample_batches``, the round function's ``local_step``,
+``encode``, ``decode``, ``mix``, ``langevin_noise`` and ``round_metrics``
+(:mod:`repro.core.algorithms`), and ``bank_admit``. On the host each chunk
+of ``run`` is the spans ``engine.dispatch`` (the chunk call),
+``engine.sync_metrics`` (the loss and consensus transfer, which waits for
+the chunk) and ``engine.histories`` (the other metric columns).
 """
 from __future__ import annotations
 
@@ -39,6 +48,7 @@ from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.posterior import DeviceSampleBank, SampleBank
@@ -100,6 +110,53 @@ _HISTORY_FIELDS = (
     ("last_abandoned_history", "abandoned", "abandoned_bytes"),
     ("last_participation_history", "participation", "participation"),
 )
+
+
+def _chunk_metrics(metrics, loss) -> ChunkMetrics:
+    """One round's ChunkMetrics row from its RoundMetrics and mean loss."""
+    with jax.named_scope("round_metrics"):
+        return ChunkMetrics(
+            loss=loss,
+            consensus=metrics.consensus_error,
+            delta_norm=metrics.delta_norm,
+            wire=metrics.wire_bytes,
+            cross=jnp.float32(metrics.cross_bytes),
+            offered=jnp.float32(metrics.offered_bytes),
+            delivered=jnp.float32(metrics.delivered_bytes),
+            airtime=jnp.float32(metrics.airtime_s),
+            energy=jnp.float32(metrics.energy_j),
+            retransmits=jnp.float32(metrics.retransmits),
+            abandoned=jnp.float32(metrics.abandoned_bytes),
+            participation=jnp.asarray(metrics.participation, jnp.float32),
+        )
+
+
+def _round_in_chunk(engine, data, sizes, state, bank, t, kround,
+                    node_ids=None):
+    """One round inside a chunk: sample the minibatches on the device, run
+    the round function, admit the params to the bank."""
+    shards_now = DeviceShards(data=data, sizes=sizes,
+                              example_field=engine.shards.example_field)
+    with jax.named_scope("sample_batches"):
+        batches = shards_now.sample(round_data_key(kround),
+                                    engine.local_steps, engine.minibatch,
+                                    node_ids=node_ids)
+    state, metrics = engine.round_fn(state, batches, kround)
+    if engine.bank is not None:
+        with jax.named_scope("bank_admit"):
+            bank = engine.bank.update(bank, t, state.params)
+    return state, bank, metrics
+
+
+def _collect(ms: ChunkMetrics, losses: List[float], cons: List[float],
+             hists: dict) -> None:
+    """A chunk's metrics to the host. The loss and consensus transfer waits
+    for the chunk; the other columns follow."""
+    with TraceAnnotation("engine.sync_metrics"):
+        losses.extend(np.asarray(ms.loss, np.float64).tolist())
+        cons.extend(np.asarray(ms.consensus, np.float64).tolist())
+    with TraceAnnotation("engine.histories"):
+        _extend_histories(hists, ms)
 
 
 def _init_histories(engine) -> None:
@@ -181,28 +238,11 @@ class ScanRoundEngine:
               ) -> Tuple[EngineCarry, ChunkMetrics]:
         state, key, bank = carry
         key, kround = jax.random.split(key)
-        shards_now = DeviceShards(data=data, sizes=sizes,
-                                  example_field=self.shards.example_field)
-        batches = shards_now.sample(round_data_key(kround),
-                                    self.local_steps, self.minibatch)
-        state, metrics = self.round_fn(state, batches, kround)
-        if self.bank is not None:
-            bank = self.bank.update(bank, t, state.params)
-        ms = ChunkMetrics(
-            loss=jnp.mean(metrics.loss),
-            consensus=metrics.consensus_error,
-            delta_norm=metrics.delta_norm,
-            wire=metrics.wire_bytes,
-            cross=jnp.float32(metrics.cross_bytes),
-            offered=jnp.float32(metrics.offered_bytes),
-            delivered=jnp.float32(metrics.delivered_bytes),
-            airtime=jnp.float32(metrics.airtime_s),
-            energy=jnp.float32(metrics.energy_j),
-            retransmits=jnp.float32(metrics.retransmits),
-            abandoned=jnp.float32(metrics.abandoned_bytes),
-            participation=jnp.asarray(metrics.participation, jnp.float32),
-        )
-        return EngineCarry(state, key, bank), ms
+        state, bank, metrics = _round_in_chunk(self, data, sizes, state,
+                                               bank, t, kround)
+        with jax.named_scope("round_metrics"):
+            loss = jnp.mean(metrics.loss)
+        return EngineCarry(state, key, bank), _chunk_metrics(metrics, loss)
 
     def _chunk_fn(self, length: int):
         if length not in self._chunk_fns:
@@ -215,6 +255,14 @@ class ScanRoundEngine:
             # donate the carry: params/v/v_bar (+ bank slots) update in place
             self._chunk_fns[length] = jax.jit(chunk, donate_argnums=(1,))
         return self._chunk_fns[length]
+
+    def lower_chunk(self, state, key, bank_state, rounds: int, t0: int = 0):
+        """The ``rounds``-round chunk program for these arguments, lowered
+        and not run (``.compile().as_text()`` gives the optimized HLO, each
+        op's round scope in its ``op_name`` metadata)."""
+        return self._chunk_fn(rounds).lower(
+            (self.shards.data, self.shards.sizes),
+            EngineCarry(state, key, bank_state), jnp.asarray(t0, jnp.int32))
 
     def run(self, state, key, bank_state, rounds: int, t0: int = 0,
             log_every: int = 0, log_cb: Optional[LogCb] = None):
@@ -235,11 +283,10 @@ class ScanRoundEngine:
         while done < rounds:
             n = min(chunk, rounds - done)
             data_sizes = (self.shards.data, self.shards.sizes)
-            carry, ms = self._chunk_fn(n)(data_sizes, carry,
-                                          jnp.asarray(t0 + done, jnp.int32))
-            losses.extend(np.asarray(ms.loss, np.float64).tolist())
-            cons.extend(np.asarray(ms.consensus, np.float64).tolist())
-            _extend_histories(hists, ms)
+            with TraceAnnotation("engine.dispatch"):
+                carry, ms = self._chunk_fn(n)(
+                    data_sizes, carry, jnp.asarray(t0 + done, jnp.int32))
+            _collect(ms, losses, cons, hists)
             done += n
             # same cadence as the host loop: only exact log_every multiples
             # (a non-aligned remainder chunk does not emit a log line)
@@ -378,38 +425,20 @@ class ShardRoundEngine:
         local_k = state.key.shape[0]
         r = jax.lax.axis_index(self.fed_axis)
         ids = r * local_k + jnp.arange(local_k, dtype=jnp.int32)
-        shards_local = DeviceShards(data=data, sizes=sizes,
-                                    example_field=self.shards.example_field)
-        batches = shards_local.sample(round_data_key(kround),
-                                      self.local_steps, self.minibatch,
-                                      node_ids=ids)
-        state, metrics = self.round_fn(state, batches, kround)
-        if self.bank is not None:
-            bank = self.bank.update(bank, t, state.params)
+        state, bank, metrics = _round_in_chunk(self, data, sizes, state,
+                                               bank, t, kround, node_ids=ids)
         # loss is shard-local (lk, L); psum for the global per-round mean.
         # consensus/delta_norm/wire/cross come out of the round fn already
-        # globally reduced (psum) or shard-invariant (static byte counts).
+        # globally reduced (psum) or shard-invariant (static byte counts);
+        # the participation vector derives from the replicated round key,
+        # so it is identical on every shard — a replicated out_spec.
         n_total = metrics.loss.size * self.num_shards
-        loss_mean = jax.lax.psum(
-            jnp.sum(metrics.loss.astype(jnp.float32)), self.fed_axis
-        ) / n_total
-        ms = ChunkMetrics(
-            loss=loss_mean,
-            consensus=metrics.consensus_error,
-            delta_norm=metrics.delta_norm,
-            wire=metrics.wire_bytes,
-            cross=jnp.float32(metrics.cross_bytes),
-            offered=jnp.float32(metrics.offered_bytes),
-            delivered=jnp.float32(metrics.delivered_bytes),
-            airtime=jnp.float32(metrics.airtime_s),
-            energy=jnp.float32(metrics.energy_j),
-            retransmits=jnp.float32(metrics.retransmits),
-            abandoned=jnp.float32(metrics.abandoned_bytes),
-            # the full-K vector is derived from the replicated round key, so
-            # it is identical on every shard — a replicated out_spec
-            participation=jnp.asarray(metrics.participation, jnp.float32),
-        )
-        return EngineCarry(state, key, bank), ms
+        with jax.named_scope("round_metrics"):
+            loss_mean = jax.lax.psum(
+                jnp.sum(metrics.loss.astype(jnp.float32)), self.fed_axis
+            ) / n_total
+        return EngineCarry(state, key, bank), _chunk_metrics(metrics,
+                                                             loss_mean)
 
     def _chunk_fn(self, length: int, carry: EngineCarry):
         if length not in self._chunk_fns:
@@ -446,11 +475,10 @@ class ShardRoundEngine:
         done = 0
         while done < rounds:
             n = min(chunk, rounds - done)
-            carry, ms = self._chunk_fn(n, carry)(
-                data_sizes, carry, jnp.asarray(t0 + done, jnp.int32))
-            losses.extend(np.asarray(ms.loss, np.float64).tolist())
-            cons.extend(np.asarray(ms.consensus, np.float64).tolist())
-            _extend_histories(hists, ms)
+            with TraceAnnotation("engine.dispatch"):
+                carry, ms = self._chunk_fn(n, carry)(
+                    data_sizes, carry, jnp.asarray(t0 + done, jnp.int32))
+            _collect(ms, losses, cons, hists)
             done += n
             if log_cb is not None and log_every and done % log_every == 0:
                 log_cb(t0 + done, losses[-1], cons[-1])
