@@ -7,6 +7,7 @@ predictive distribution that gives the calibration gains the paper measures.
 """
 from __future__ import annotations
 
+import contextvars
 import warnings
 from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
@@ -272,6 +273,21 @@ def bank_age_weights(rounds, now: int, window: int = 0,
     return w / total
 
 
+# Set while a BankPredictor traces its program: a model's batching rule
+# appends to it when it runs the bank's members as one packed forward.
+_PACKED_FORWARDS: contextvars.ContextVar = contextvars.ContextVar(
+    "packed_forwards", default=None)
+
+
+def note_packed_forward() -> None:
+    """Record, at trace time, that a model's batching rule ran the members
+    of the bank being traced as one packed forward (e.g. the LeNet conv
+    tower with the member axis in the channels)."""
+    seen = _PACKED_FORWARDS.get()
+    if seen is not None:
+        seen.append(True)
+
+
 def bma_predict_stacked(apply_fn: Callable, stacked, batch,
                         node_axis: Optional[int] = None,
                         weights=None) -> jnp.ndarray:
@@ -347,6 +363,10 @@ class BankPredictor(PosteriorPredictor):
     the ensemble dimension is a parallel axis, not a loop.
 
     ``install(stacked, weights=None)`` keeps the uniform-mean graph bitwise pre-§15; an age-weight vector routes to a separately-jitted weighted branch.
+
+    ``packed_traces`` and ``per_member_traces`` count the traces of the
+    predict programs by whether the model ran the bank as one packed
+    forward (:func:`note_packed_forward`) or member by member.
     """
 
     def __init__(self, apply_fn: Callable, stacked: Any = None,
@@ -360,19 +380,31 @@ class BankPredictor(PosteriorPredictor):
         self._fn_weighted = jax.jit(self._predict_weighted)
         self._stacked = None
         self._weights = None
+        self.packed_traces = 0
+        self.per_member_traces = 0
         if stacked is not None:
             self.install(stacked)
 
-    def _predict(self, stacked, batch):
-        probs = bma_predict_stacked(self.apply_fn, stacked, batch,
-                                    node_axis=self.node_axis)
+    def _bma(self, stacked, batch, weights=None):
+        seen = []
+        token = _PACKED_FORWARDS.set(seen)
+        try:
+            probs = bma_predict_stacked(self.apply_fn, stacked, batch,
+                                        node_axis=self.node_axis,
+                                        weights=weights)
+        finally:
+            _PACKED_FORWARDS.reset(token)
+        if seen:
+            self.packed_traces += 1
+        else:
+            self.per_member_traces += 1
         return probs, predictive_entropy(probs)
 
+    def _predict(self, stacked, batch):
+        return self._bma(stacked, batch)
+
     def _predict_weighted(self, stacked, weights, batch):
-        probs = bma_predict_stacked(self.apply_fn, stacked, batch,
-                                    node_axis=self.node_axis,
-                                    weights=weights)
-        return probs, predictive_entropy(probs)
+        return self._bma(stacked, batch, weights)
 
     # -- bank lifecycle ----------------------------------------------------
     def install(self, stacked, weights=None) -> None:

@@ -17,7 +17,9 @@ def _make_lenet(cfg) -> SimpleNamespace:
         return _lenet.lenet_loss(params, batch, key)
 
     def logits(params, batch):
-        return _lenet.lenet_logits(params, batch["x"])
+        # forward only (a custom_vmap has no reverse mode): differentiate
+        # ``loss``, which calls the plain forward
+        return _lenet.lenet_predict(params, batch["x"])
 
     return SimpleNamespace(cfg=cfg, init=init, loss=loss, logits=logits,
                            init_decode_state=None, decode_step=None)

@@ -320,6 +320,15 @@ class ClassifyEngine(ServingEngine):
     def compile_count(self) -> int:
         return self.predictor.compile_count() + self._write._cache_size()
 
+    def stats(self) -> Dict[str, float]:
+        """The shared stats, and how the predict programs ran the bank:
+        traces that ran it as one packed forward, and member by member."""
+        out = super().stats()
+        out["bma_packed_compiles"] = float(self.predictor.packed_traces)
+        out["bma_per_member_compiles"] = float(
+            self.predictor.per_member_traces)
+        return out
+
 
 class DecodeEngine(ServingEngine):
     """Continuous batching for autoregressive decode under BMA.

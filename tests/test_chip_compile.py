@@ -1,9 +1,11 @@
-"""The encode-path Pallas kernels compile for a TPU v5e (``interpret=False``).
+"""The encode-path Pallas kernels compile for a TPU v5e (``interpret=False``),
+and so does the serving cell's BMA predict program.
 
-Each case lowers one kernel at a real leaf width and compiles it for one
-chip of a described ``v5e:2x2`` topology: the TPU compiler runs here with
-no chip attached and refuses what Mosaic cannot lower, which interpret mode
-never shows. Nothing executes, so these say nothing about results or times.
+Each kernel case lowers one kernel at a real leaf width and compiles it for
+one chip of a described ``v5e:2x2`` topology: the TPU compiler runs here
+with no chip attached and refuses what Mosaic cannot lower, which interpret
+mode never shows. Nothing executes, so these say nothing about results or
+times.
 
 The topology is described inside a module fixture — never at import, in a
 ``skipif`` or in ``parametrize`` — so every test worker collects the same
@@ -11,16 +13,21 @@ cases and only the worker that runs this file loads the TPU library. The
 persistent compilation cache is off around the compiles: an entry written
 for a described chip cannot be read back without one.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.config import get_arch
+from repro.core.posterior import BankPredictor
 from repro.kernels.block_topk import ROWS_PER_TILE, block_topk_pallas
 from repro.kernels.fused_compress import delta_pack_pallas, grid_quant_pallas
 from repro.kernels.pack import pack_topk_pallas, unpack_topk_pallas
 from repro.kernels.qsgd import TILE_C, TILE_R, qsgd_pallas
+from repro.models import get_model
 
 BLOCK, RATIO, LEVELS = 1024, 0.01, 16
 K = 11                                   # ceil(RATIO * BLOCK)
@@ -88,3 +95,26 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, leaf):
             for s, d in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bma_predict_compiles_packed_for_v5e(one_chip):
+    """The predict program of the serving cell (a 40 x 10 bank at full
+    LeNet width, 8 slots of 256 x 63 maps) compiles for the chip with the
+    conv tower packed (the ``bma_packed`` scope), and fc1 reads the
+    float32 bank in place: the bfloat16 conversion of the bank stays inside
+    fc1's fusion, no op of the program's entry writes a copy of it."""
+    cfg = get_arch("lenet-radar").config
+    model = get_model(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    bank = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (40, 10) + a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((8,) + tuple(cfg.input_hw) + (1,), jnp.float32,
+                             sharding=one_chip)
+    predictor = BankPredictor(lambda p, b: model.logits(p, b), node_axis=1)
+    text = predictor._fn.lower(bank, {"x": x}).compile().as_text()
+    assert "bma_packed" in text and predictor.packed_traces == 1
+    fc1 = 400 * params["fc1"]["w"].size
+    entry = re.search(r"^ENTRY .*?^}", text, re.M | re.S).group(0)
+    bf16_sizes = [math.prod(int(n) for n in d.split(",") if n)
+                  for d in re.findall(r"bf16\[([0-9,]*)\]", entry)]
+    assert fc1 not in bf16_sizes

@@ -235,3 +235,63 @@ def test_gshard_capacity_drop_error_decreases():
     eps = 1e-6
     assert rels[0] >= rels[1] - eps >= rels[2] - 2 * eps, rels
     assert rels[2] < 1e-4, rels
+
+
+# -- the packed BMA forward of lenet-radar ----------------------------------
+
+PACKED_HW = (32, 31)                    # odd conv2 output width: a cropped pool
+
+
+@pytest.mark.parametrize("mode", ["samples", "nodes", "weighted",
+                                  "batched_x", "outer_x"])
+@pytest.mark.parametrize("members", [1, 3, 8, 12, 16])
+def test_lenet_packed_bma_matches_member_loop(members, mode):
+    """``bma_predict_stacked`` over ``model.logits`` runs the conv tower
+    once for the bank (members packed into the channels, groups of 8, zero
+    members padding 3 and 12) and equals a Python loop over the members'
+    ``lenet_logits``; with ``x`` batched per member the batching rule falls
+    back to exactly the plain per-member vmap, and under an outer vmap over
+    inputs each input's members are still packed."""
+    from repro.core.posterior import bma_predict_stacked
+    from repro.models.lenet import lenet_logits
+    model = get_model(get_arch("lenet-radar").reduced.replace(
+        input_hw=PACKED_HW))
+    ps = [model.init(jax.random.fold_in(KEY, i)) for i in range(members)]
+    flat = jax.tree.map(lambda *xs: jnp.stack(xs), *ps)
+    x = jax.random.normal(jax.random.fold_in(KEY, 99),
+                          (5,) + PACKED_HW + (1,))
+    apply = lambda p, b: model.logits(p, b)
+    if mode == "batched_x":
+        xs = x[None] + 0.1 * jnp.arange(members)[:, None, None, None, None]
+        got = jax.vmap(lambda p, xx: model.logits(p, {"x": xx}))(flat, xs)
+        want = jax.vmap(lenet_logits)(flat, xs)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    if mode == "outer_x":
+        xs = jnp.stack([x, -x])
+        with jax.default_matmul_precision("highest"):
+            got = jax.vmap(lambda xx: jax.vmap(
+                lambda p: model.logits(p, {"x": xx}))(flat))(xs)
+            want = np.stack([[lenet_logits(p, xx) for p in ps] for xx in xs])
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-5)
+        return
+    k = 4 if members % 4 == 0 else 1
+    nodes = jax.tree.map(lambda a: a.reshape((members // k, k) + a.shape[1:]),
+                         flat)
+    w = 1.0 + jnp.arange(members // k, dtype=jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        member_probs = np.stack([np.asarray(jax.nn.softmax(
+            lenet_logits(p, x), axis=-1)) for p in ps])
+        if mode == "samples":
+            got = bma_predict_stacked(apply, flat, {"x": x})
+            want = member_probs.mean(0)
+        elif mode == "nodes":
+            got = bma_predict_stacked(apply, nodes, {"x": x}, node_axis=1)
+            want = member_probs.mean(0)
+        else:
+            got = bma_predict_stacked(apply, nodes, {"x": x}, node_axis=1,
+                                      weights=w)
+            per_sample = member_probs.reshape((members // k, k, 5, -1))
+            want = np.einsum("s,s...->...", np.asarray(w / w.sum()),
+                             per_sample.mean(1))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-5)

@@ -8,12 +8,17 @@ and the serving engine's slot counters.
   steps write the ``engine.*`` and ``serve.*`` host spans, nested as the
   engines document, ``serve.step`` carrying its step index;
 * the serving engine counts occupied slots and stamps each response with
-  the steps it waited.
+  the steps it waited;
+* training never takes the packed BMA forward of ``model.logits``: its
+  chunk program holds no ``bma_packed`` scope, and ``vmap(grad(loss))``
+  differentiates the plain forward; the serving engine counts its packed
+  predict traces.
 """
 import glob
 import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -167,3 +172,53 @@ def test_slot_counters_and_queue_stamps():
     for q in (50, 99):
         assert st[f"p{q}_ms"] == pytest.approx(np.percentile(lat_ms, q),
                                                rel=0.05)
+
+
+def test_training_chunk_carries_no_packed_forward():
+    engine, state, bank = _training(fused=True)
+    text = engine.lower_chunk(state, jax.random.PRNGKey(2), bank,
+                              rounds=2).compile().as_text()
+    scopes = _scopes_in(text)
+    assert "local_step" in scopes and "bma_packed" not in scopes
+
+
+def test_vmap_grad_of_the_loss_differentiates_over_nodes():
+    """The nodes' gradients under ``vmap(grad(model.loss))`` equal each
+    node's own: the loss calls the plain forward, which has reverse mode
+    (the ``custom_vmap`` of ``model.logits`` has none)."""
+    model = _lenet()
+    params = [model.init(jax.random.PRNGKey(i)) for i in range(K)]
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *params)
+    data = make_dataset(K * B, hw=HW, seed=3)
+    batches = {"x": jnp.asarray(data["x"]).reshape((K, B) + HW + (1,)),
+               "y": jnp.asarray(data["y"]).reshape(K, B)}
+    grad = jax.grad(lambda p, b: model.loss(p, b)[0])
+    got = jax.jit(jax.vmap(grad))(stacked, batches)
+    for i in range(K):
+        want = grad(params[i], jax.tree.map(lambda a: a[i], batches))
+        jax.tree.map(lambda g, w: np.testing.assert_allclose(
+            np.asarray(g[i]), np.asarray(w), rtol=1e-5, atol=1e-6),
+            got, want)
+
+
+@pytest.mark.parametrize("forward", ["model.logits", "lenet_logits"])
+def test_classify_stats_count_packed_predict_traces(forward):
+    """A ClassifyEngine built as the serving benchmark builds it (an
+    (S, K, ...) bank, ``node_axis=1``) runs the bank as one packed forward;
+    a forward without the batching rule runs it member by member."""
+    from repro.models.lenet import lenet_logits
+    model = _lenet()
+    params = [model.init(jax.random.PRNGKey(i)) for i in range(2 * K)]
+    bank = jax.tree.map(
+        lambda *xs: jnp.stack(xs).reshape((2, K) + xs[0].shape), *params)
+    apply = ((lambda p, b: model.logits(p, b)) if forward == "model.logits"
+             else (lambda p, b: lenet_logits(p, b["x"])))
+    serve = ClassifyEngine(apply, ServeConfig(slots=2),
+                           input_shape=HW + (1,), stacked=bank, node_axis=1)
+    for x in make_dataset(3, hw=HW, seed=6)["x"]:
+        serve.submit(ServeRequest(x=x))
+    assert len(serve.drain()) == 3
+    st = serve.stats()
+    packed = forward == "model.logits"
+    assert st["bma_packed_compiles"] == float(packed)
+    assert st["bma_per_member_compiles"] == float(not packed)
