@@ -340,7 +340,6 @@ class TrainConfig:
     grad_clip: float = 0.0
     warmup_steps: int = 0
     param_dtype: Any = "float32"
-    remat: bool = False
 
 
 @dataclass(frozen=True)

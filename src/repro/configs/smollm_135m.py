@@ -1,5 +1,6 @@
 """smollm-135m [dense]: 30L d_model=576 9H (GQA kv=3) d_ff=1536 vocab=49152 —
-llama-arch small. [hf:HuggingFaceTB/SmolLM-135M]"""
+llama-arch small, SwiGLU, RMSNorm (eps 1e-5), RoPE theta 10,000, tied
+embedding, 2,048-token context. [hf:HuggingFaceTB/SmolLM-135M]"""
 from repro.config import ArchSpec, ModelConfig, register_arch
 
 CONFIG = ModelConfig(
@@ -11,6 +12,10 @@ CONFIG = ModelConfig(
     num_kv_heads=3,
     d_ff=1536,
     vocab_size=49152,
+    max_seq_len=2048,
+    rope_theta=10000.0,
+    norm_eps=1e-5,
+    act="silu",
     tie_embeddings=True,
 )
 

@@ -62,20 +62,22 @@ def init_block(key, spec: BlockSpec, cfg, dtype=jnp.float32) -> Dict:
 
 
 def apply_block(params, x, positions, spec: BlockSpec, cfg):
-    """Training/prefill. Returns (x, aux_loss)."""
+    """Training/prefill. Returns (x, aux_loss). The mixer, of whatever
+    kind, runs under the named scope ``attention``."""
     mixer, ffn = spec
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
-    if mixer in ("attn", "local_attn"):
-        h = attn_mod.attention(params["attn"], h, positions, cfg,
-                               window=_mixer_window(mixer, cfg))
-    elif mixer == "mla":
-        h = mla_mod.mla_attention(params["mla"], h, positions, cfg)
-    elif mixer == "rec":
-        h = rglru_mod.rglru_block(params["rec"], h, cfg)
-    elif mixer == "mlstm":
-        h = xlstm_mod.mlstm_block(params["mlstm"], h, cfg)
-    elif mixer == "slstm":
-        h = xlstm_mod.slstm_block(params["slstm"], h, cfg)
+    with jax.named_scope("attention"):
+        if mixer in ("attn", "local_attn"):
+            h = attn_mod.attention(params["attn"], h, positions, cfg,
+                                   window=_mixer_window(mixer, cfg))
+        elif mixer == "mla":
+            h = mla_mod.mla_attention(params["mla"], h, positions, cfg)
+        elif mixer == "rec":
+            h = rglru_mod.rglru_block(params["rec"], h, cfg)
+        elif mixer == "mlstm":
+            h = xlstm_mod.mlstm_block(params["mlstm"], h, cfg)
+        elif mixer == "slstm":
+            h = xlstm_mod.slstm_block(params["slstm"], h, cfg)
     x = x + h
     aux = jnp.zeros((), jnp.float32)
     if ffn == "mlp":

@@ -5,6 +5,16 @@ architecture's block pattern (dense: 1 block; RecurrentGemma: (rec, rec,
 attn); xLSTM: 7×mLSTM + 1×sLSTM), keeping HLO size O(unit) instead of
 O(num_layers). Remainder layers are unrolled as a tail.
 
+The training ``loss`` keeps memory to what a 2,048-token batch can hold
+next to the federated state: the scanned layer body runs under
+``jax.checkpoint`` (the backward keeps each layer's input and recomputes the
+rest), and the cross-entropy over the tied head is taken ``chunk_size``
+tokens at a time, each chunk under ``jax.checkpoint``, so the ``(B, S, V)``
+float32 logits never exist. Named scopes ``attention`` (every block's
+mixer) and ``xent`` (final norm, head product, log-sum-exp and target
+gather) mark the two in the compiled program; ``chunked_xent_traces``
+counts the traces of the chunked loss.
+
 Public surface (per cfg):
     init(key)                                   -> params
     loss(params, batch, key)                    -> (mean_nll, aux)
@@ -14,6 +24,7 @@ Public surface (per cfg):
 """
 from __future__ import annotations
 
+from functools import partial
 from types import SimpleNamespace
 from typing import Dict, List, Tuple
 
@@ -118,7 +129,9 @@ def make_model(cfg) -> SimpleNamespace:
         return x @ w
 
     # -- forward -----------------------------------------------------------
-    def _trunk(params, x):
+    def _trunk(params, x, remat=False):
+        """``remat``: recompute each scanned layer group in the backward,
+        keeping only its input."""
         b, s, _ = x.shape
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
         aux = jnp.zeros((), jnp.float32)
@@ -130,6 +143,8 @@ def make_model(cfg) -> SimpleNamespace:
                     a = a + ai
                 return (h, a), None
 
+            if remat:
+                body = jax.checkpoint(body, prevent_cse=False)
             (x, aux), _ = jax.lax.scan(body, (x, aux), params["groups"])
             for i, spec in enumerate(tail):
                 x, ai = blk.apply_block(params["tail"][i], x, positions, spec, cfg)
@@ -145,21 +160,58 @@ def make_model(cfg) -> SimpleNamespace:
         x, _ = _trunk(params, _embed(params, batch))
         return _head(params, x)
 
+    def _xent_sum(params, x, targets, weights):
+        """Sum of ``weights * (logsumexp(logits) - logits[target])`` over the
+        tokens of ``x (N, D)``, the logits of ``chunk_size`` tokens at a
+        time. Each chunk's logits are the bf16 (``cfg.dtype``) head product
+        taken to float32, as ``_head`` gives them; the head weight enters
+        each chunk in its stored dtype, so its gradient sums over the chunks
+        in that dtype."""
+        n = x.shape[0]
+        c = min(cfg.chunk_size, n)
+        pad = -n % c
+        if pad:
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+            targets = jnp.pad(targets, (0, pad))
+            weights = jnp.pad(weights, (0, pad))
+        head = (params["embed"]["tok"] if cfg.tie_embeddings
+                else params["lm_head"])
+
+        @partial(jax.checkpoint, prevent_cse=False)
+        def chunk(norm, head, xc, tc, wc):
+            h = rmsnorm(norm, xc, cfg.norm_eps)
+            w = head.astype(dtype)
+            lg = (h @ (w.T if cfg.tie_embeddings else w)).astype(jnp.float32)
+            lse = jax.nn.logsumexp(lg, axis=-1)
+            tgt = jnp.take_along_axis(lg, tc[:, None], axis=-1)[:, 0]
+            return jnp.sum(wc * (lse - tgt))
+
+        def body(total, xs):
+            return total + chunk(params["final_norm"], head, *xs), None
+
+        total, _ = jax.lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            (x.reshape(-1, c, x.shape[-1]), targets.reshape(-1, c),
+             weights.reshape(-1, c)))
+        return total
+
     def loss(params, batch, key=None):
-        x, aux = _trunk(params, _embed(params, batch))
-        lg = _head(params, x)
+        model.chunked_xent_traces += 1
+        x, aux = _trunk(params, _embed(params, batch), remat=True)
         tokens = batch["tokens"]
-        n_img = lg.shape[1] - tokens.shape[1]
-        lg = lg[:, n_img:]                      # only text positions
-        logp = jax.nn.log_softmax(lg[:, :-1].astype(jnp.float32), axis=-1)
-        tgt = tokens[:, 1:]
-        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        b, s = tokens.shape
+        x = x[:, x.shape[1] - s:]               # only text positions
+        # position t predicts token t + 1; the last position predicts none
+        targets = jnp.concatenate(
+            [tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
         mask = batch.get("loss_mask")
-        if mask is not None:
-            m = mask[:, 1:].astype(jnp.float32)
-            mean_nll = jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
-        else:
-            mean_nll = jnp.mean(nll)
+        m = (jnp.ones((b, s - 1), jnp.float32) if mask is None
+             else mask[:, 1:].astype(jnp.float32))
+        weights = jnp.concatenate([m, jnp.zeros((b, 1), jnp.float32)], axis=1)
+        with jax.named_scope("xent"):
+            total = _xent_sum(params, x.reshape(b * s, -1),
+                              targets.reshape(-1), weights.reshape(-1))
+        mean_nll = total / jnp.maximum(jnp.sum(m), 1.0)
         return mean_nll + aux, {"nll": mean_nll, "aux": aux}
 
     # -- decode ------------------------------------------------------------
@@ -214,8 +266,10 @@ def make_model(cfg) -> SimpleNamespace:
             new_cache = {"layers": lc}
         return new_cache, _head(params, x)
 
-    return SimpleNamespace(
+    model = SimpleNamespace(
         cfg=cfg, init=init, loss=loss, logits=logits,
         init_decode_state=init_decode_state, decode_step=decode_step,
         pattern=full_pattern(cfg), scan_unit=(unit, n_groups, tail),
+        chunked_xent_traces=0,
     )
+    return model

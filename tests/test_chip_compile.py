@@ -1,5 +1,6 @@
 """The encode-path Pallas kernels compile for a TPU v5e (``interpret=False``),
-and so does the serving cell's BMA predict program.
+and so do the serving cell's BMA predict program and the SmolLM training
+cell's chunk program.
 
 Each kernel case lowers one kernel at a real leaf width and compiles it for
 one chip of a described ``v5e:2x2`` topology: the TPU compiler runs here
@@ -118,3 +119,57 @@ def test_bma_predict_compiles_packed_for_v5e(one_chip):
     bf16_sizes = [math.prod(int(n) for n in d.split(",") if n)
                   for d in re.findall(r"bf16\[([0-9,]*)\]", entry)]
     assert fc1 not in bf16_sizes
+
+
+def test_smollm_train_chunk_fits_one_v5e(one_chip):
+    """The chunk program of the ``smollm-135m.train-k4-ring`` cell (K=4
+    nodes of the whole 30-layer model, 4 x 2,048-token sequences a local
+    step, float32 params, v and v-bar) compiles for one chip through the
+    chunked, recomputed loss, and its arguments and temporaries fit a
+    16 GB chip with room for the process's other buffers."""
+    from bench import common, train
+    from bench.reference import smollm as ref
+    from repro.config import FedConfig, TopologyConfig
+    from repro.core import (build_topology, init_fed_state, make_compressor,
+                            make_round_fn)
+    from repro.data.partition import DeviceShards
+    from repro.train.engine import EngineCarry, make_engine
+
+    cfg = common.find("configs", "smollm-135m")
+    tr = common.find("traffic", "train-k4-ring")
+    model = get_model(train.program_model_config(cfg))
+    topo = TopologyConfig(graph=tr["graph"])
+    fed = FedConfig(num_nodes=tr["nodes"], local_steps=tr["local_steps"],
+                    eta=tr["eta"], zeta=tr["zeta"], topology=tr["graph"],
+                    topology_cfg=topo, compressor=tr["codec"],
+                    compress_ratio=tr["ratio"], block_size=tr["block"],
+                    fused_compress=tr["fused"], algorithm="cdbfl")
+    round_fn = make_round_fn("cdbfl", model.loss, fed,
+                             build_topology(topo, fed.num_nodes).omega,
+                             make_compressor(fed))
+    params = jax.eval_shape(lambda: ref.init(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda p: init_fed_state(
+        p, fed, key=jax.random.PRNGKey(1)), params)
+    data = {"tokens": jax.ShapeDtypeStruct(
+        (tr["nodes"], tr["pool"], tr["seq_len"]), jnp.int32)}
+    sizes = jax.ShapeDtypeStruct((tr["nodes"],), jnp.int32)
+    engine = make_engine("scan", round_fn, DeviceShards(
+        data=data, sizes=sizes, example_field="tokens"), fed.local_steps,
+        tr["batch"], chunk=tr["chunk"])
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    compiled = engine._chunk_fn(tr["chunk"]).lower(
+        on_chip((data, sizes)), on_chip(EngineCarry(state, key, None)),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert model.chunked_xent_traces == 1
+    text = compiled.as_text()
+    assert "xent" in text and "attention" in text
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 14.76 GB when written: the chunked loss and recomputation; without
+    # them the float32 logits of 16 x 2,048 tokens alone take 6.4 GB
+    assert peak < 15.5e9, peak
