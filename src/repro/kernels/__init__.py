@@ -5,6 +5,8 @@
 * fused_update — paper Eq. 9 (consensus correction + Langevin noise) in one
   memory-bound pass.
 * qsgd — stochastic quantization (paper ref [26]) with contraction scaling.
+* flash_attention — causal GQA attention for the LM training path, forward
+  and backward, with each block's scores kept in VMEM.
 
 ops.py: jit'd wrappers (padding/tiling); ref.py: pure-jnp oracles.
 The wrappers take their mode from :func:`interpret_mode`: compiled by

@@ -2,6 +2,13 @@
 sliding-window variant (Mistral-style), plus single-token decode with either
 a full KV cache or a fixed-size ring-buffer (windowed) cache.
 
+Training/prefill attention takes one of three paths. On a TPU, causal
+full attention at a shape the fused kernel takes runs as that kernel
+(``repro.kernels.flash_attention``); otherwise long sequences take
+``chunked_gqa`` and short ones the naive masked form, which stays the
+correctness oracle. ``flash_attention_traces`` and
+``chunked_attention_traces`` count the traces that took the first two.
+
 Shapes: x (B, S, D); q (B, S, H, hd); k/v (B, S, KV, hd).
 """
 from __future__ import annotations
@@ -11,9 +18,14 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention, interpret_mode
 from repro.models.layers import apply_rope, dense_init
 
 NEG_INF = -1e30
+
+# trace-time counters of the paths ``attention`` took
+flash_attention_traces = 0
+chunked_attention_traces = 0
 
 
 def init_attention(key, cfg, dtype=jnp.float32) -> Dict:
@@ -72,6 +84,7 @@ def attention(params, x, positions, cfg, window: int = 0,
     ``cross_kv`` switches to cross-attention (whisper decoder): keys/values
     are provided and no causal mask is applied.
     """
+    global flash_attention_traces, chunked_attention_traces
     dt = x.dtype
     if cross_kv is not None:
         k, v = cross_kv
@@ -80,7 +93,13 @@ def attention(params, x, positions, cfg, window: int = 0,
         return _gqa_out(scores, v, params, dt)
 
     q, k, v = _qkv(params, x, cfg, positions)
-    s = q.shape[1]
+    s, hd = q.shape[1], q.shape[-1]
+    g = q.shape[2] // k.shape[2]
+    if (causal and window == 0 and cfg.attn_impl == "auto"
+            and not interpret_mode() and flash_attention.supported(s, hd, g)):
+        flash_attention_traces += 1
+        ctx = flash_attention.flash_gqa(q, k, v)
+        return jnp.einsum("bshk,hkd->bsd", ctx, params["wo"].astype(dt))
     use_chunked = causal and (
         cfg.attn_impl == "chunked"
         or (cfg.attn_impl == "auto" and s >= 2 * cfg.chunk_size
@@ -88,8 +107,8 @@ def attention(params, x, positions, cfg, window: int = 0,
     )
     if use_chunked:
         from repro.models.chunked import chunked_gqa
+        chunked_attention_traces += 1
         ctx = chunked_gqa(q, k, v, window=window, chunk=cfg.chunk_size)
-        b, sq = ctx.shape[0], ctx.shape[1]
         return jnp.einsum("bshk,hkd->bsd", ctx, params["wo"].astype(dt))
     scores = _gqa_scores(q, k)
     sq, sk = scores.shape[-2], scores.shape[-1]

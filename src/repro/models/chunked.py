@@ -4,11 +4,14 @@ Naive attention materializes (B, H, S, S) scores — 825 TB for
 mistral-large at train_4k — and the recurrent blocks' scan residuals are
 similarly O(S) fp32. These chunked forms bound live memory to
 O(chunk · S) (attention) or O(chunk) (recurrences), with
-``jax.checkpoint`` making the backward recompute per chunk. This is the
-TPU/production formulation (flash-attention-style online softmax; GLA-style
-chunkwise mLSTM); the naive forms in attention.py/xlstm.py remain the
-correctness oracles, and the naive→chunked delta is quantified in
-EXPERIMENTS.md §Perf.
+``jax.checkpoint`` making the backward recompute per chunk. ``chunked_gqa``
+is the long-sequence attention path off the TPU, and on it for MLA and
+windowed attention; causal full attention on a TPU takes the fused kernel
+in ``repro.kernels.flash_attention`` instead. Each query chunk's float32
+scores against every key, the masked ones too, are written to HBM. The
+recurrences take the GLA-style chunkwise mLSTM form. The naive forms in
+attention.py/xlstm.py remain the correctness oracles, and the
+naive→chunked delta is quantified in EXPERIMENTS.md §Perf.
 """
 from __future__ import annotations
 

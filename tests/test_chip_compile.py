@@ -121,19 +121,30 @@ def test_bma_predict_compiles_packed_for_v5e(one_chip):
     assert fc1 not in bf16_sizes
 
 
-def test_smollm_train_chunk_fits_one_v5e(one_chip):
+def test_smollm_train_chunk_fits_one_v5e(one_chip, monkeypatch):
     """The chunk program of the ``smollm-135m.train-k4-ring`` cell (K=4
     nodes of the whole 30-layer model, 4 x 2,048-token sequences a local
     step, float32 params, v and v-bar) compiles for one chip through the
-    chunked, recomputed loss, and its arguments and temporaries fit a
-    16 GB chip with room for the process's other buffers."""
-    from bench import common, train
+    chunked, recomputed loss and the fused attention kernel, and its
+    arguments and temporaries fit a 16 GB chip with room for the process's
+    other buffers.
+
+    The host here is a CPU, so the platform checks of the dispatch and of
+    the kernel are patched to take the TPU's path. The kernel's forward and
+    backward are custom calls under the ``attention`` scope, and no
+    float32 block of 512 queries' scores against all 2,048 keys is left."""
+    from bench import common, lm_scopes, scopes, train
     from bench.reference import smollm as ref
     from repro.config import FedConfig, TopologyConfig
     from repro.core import (build_topology, init_fed_state, make_compressor,
                             make_round_fn)
     from repro.data.partition import DeviceShards
+    from repro.kernels import flash_attention
+    from repro.models import attention
     from repro.train.engine import EngineCarry, make_engine
+    monkeypatch.setattr(attention, "interpret_mode", lambda: False)
+    monkeypatch.setattr(flash_attention, "interpret_mode", lambda: False)
+    flash_before = attention.flash_attention_traces
 
     cfg = common.find("configs", "smollm-135m")
     tr = common.find("traffic", "train-k4-ring")
@@ -165,11 +176,26 @@ def test_smollm_train_chunk_fits_one_v5e(one_chip):
         on_chip((data, sizes)), on_chip(EngineCarry(state, key, None)),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
     assert model.chunked_xent_traces == 1
+    assert attention.flash_attention_traces > flash_before
     text = compiled.as_text()
     assert "xent" in text and "attention" in text
+    # the kernels as the benchmark's scope reader sees them: every one
+    # under ``attention``, all of its time given to ``attention_ms``
+    ops = scopes.parse_hlo(text)
+    shares = scopes.op_shares(text, scopes.ROUND_SCOPES + lm_scopes.LM_SCOPES)
+    kernels = [name for name, op in ops.items() if op.opcode == "custom-call"
+               and name.startswith("flash_attention_")]
+    assert {name.split(".")[0] for name in kernels} == {
+        "flash_attention_fwd", "flash_attention_bwd"}, kernels
+    for name in kernels:
+        assert scopes.scope_of(ops[name].op_name, ("attention",)), name
+        assert shares[name] == {"attention": 1.0}, (name, shares[name])
+    assert not re.search(r"f32\[[0-9,]*512,2048\]", text)
     mem = compiled.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    # 14.76 GB when written: the chunked loss and recomputation; without
-    # them the float32 logits of 16 x 2,048 tokens alone take 6.4 GB
+    # 14.758 GB with the fused attention kernel, 14.758 with chunked_gqa:
+    # the peak is not in attention; without the chunked loss and
+    # recomputation the float32 logits of 16 x 2,048 tokens alone take
+    # 6.4 GB
     assert peak < 15.5e9, peak
