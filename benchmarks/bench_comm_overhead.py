@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import get_arch, list_archs
-from repro.core.compression import Compressor
+from repro.core.compression import parse_pipeline
 from repro.models import get_model
 
 
@@ -25,11 +25,11 @@ def _tree_specs(cfg):
 def run(quick: bool = False) -> List[str]:
     rows = []
     compressors = {
-        "dense_fp32": Compressor(name="identity"),
-        "topk_1pct": Compressor(name="topk", ratio=0.01),
-        "block_topk_1pct": Compressor(name="block_topk", ratio=0.01),
-        "qsgd_4bit": Compressor(name="qsgd", qsgd_levels=16),
-        "sign_1bit": Compressor(name="sign"),
+        "dense_fp32": parse_pipeline("identity"),
+        "topk_1pct": parse_pipeline("topk", ratio=0.01),
+        "block_topk_1pct": parse_pipeline("block_topk", ratio=0.01),
+        "qsgd_4bit": parse_pipeline("qsgd", qsgd_levels=16),
+        "sign_1bit": parse_pipeline("sign"),
     }
 
     # paper model at full scale (2.7M params, real 256x63 maps)

@@ -7,16 +7,16 @@ performance is assessed structurally in EXPERIMENTS.md §Roofline.
 
 ``--tiny`` writes the machine-independent gate records under
 ``results/kernels/`` for check_regression: live bitwise-parity bits
-(pack/unpack round-trip, QSGD kernel vs the codec stage under jit, fused
-delta-pack vs pack-after-materialize) and the fused-update HBM traffic
-model — exact integers, safe to hard-gate.
+(pack/unpack round-trip vs the bisection reference, the QSGD carrier
+kernel vs the codec stage under jit, fused delta-pack vs
+pack-after-materialize) and the fused-update HBM traffic model — exact
+integers, safe to hard-gate.
 
     PYTHONPATH=src python -m benchmarks.bench_kernels [--tiny|--quick]
 """
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 from typing import List
@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import timeit
+from repro.core.compression import QSGDCodec
 from repro.kernels import ops, ref
 
 KEY = jax.random.PRNGKey(0)
@@ -37,16 +38,20 @@ def _parity_record(n: int) -> dict:
     (all checks under jit — the kernels' bitwise contract; see
     tests/test_kernels.py on why eager differs in the last ulp)."""
     x = jax.random.normal(KEY, (n,))
-    # pack -> unpack round-trips to the dense masked leaf
+    # pack -> unpack round-trips to the bisection reference's masked leaf
     vals, idx = ops.block_topk_pack(x, ratio=0.01, block_size=1024)
     back = ops.block_topk_unpack(vals, idx, n, (n,), block_size=1024)
-    dense = ops.block_topk(x, ratio=0.01, block_size=1024)
+    x2d, _ = ops._pad_to_2d(x, 1024, 8)
+    dense = ref.block_topk_bisect_ref(x2d, 11).reshape(-1)[:n]
     pack_rt = int(np.array_equal(np.asarray(back), np.asarray(dense)))
-    # qsgd kernel vs the jitted codec stage
-    from repro.core.compression import _qsgd_leaf
-    want = jax.jit(functools.partial(_qsgd_leaf, levels=16))(x, KEY)
-    got = ops.qsgd(x, KEY, levels=16)
-    qsgd_match = int(np.array_equal(np.asarray(got), np.asarray(want)))
+    # qsgd carrier kernel vs the jitted codec stage, on the packed carrier
+    got = jax.jit(lambda c, k: ops.qsgd_quantize_carrier(c, k, levels=16))(
+        vals, KEY)
+    want = jax.jit(lambda c, k: QSGDCodec(levels=16).encode(c, k)[:2])(
+        vals, KEY)
+    qsgd_match = int(np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+                     and np.array_equal(np.asarray(got[1]).reshape(1),
+                                        np.asarray(want[1]["scale"])))
     # fused delta-pack vs pack of the materialized residual
     v = 0.1 * jax.random.normal(jax.random.fold_in(KEY, 1), (n,))
     fv, fi = ops.fused_delta_pack(x, v, ratio=0.01, block_size=1024)
@@ -82,12 +87,10 @@ def run(quick: bool = False, tiny: bool = False) -> List[str]:
     n = 2 ** 18 if quick else 2 ** 21   # 2M params ~ the paper's LeNet
     x = jax.random.normal(KEY, (n,))
 
-    # block top-k
-    t_pallas = timeit(lambda: ops.block_topk(x, ratio=0.01), iters=3)
+    # block top-k reference (the pack kernel's row is bench_wire's)
     x2d, _ = ops._pad_to_2d(x, 1024, 8)
     jref = jax.jit(lambda a: ref.block_topk_ref(a, k=11))
     t_ref = timeit(lambda: jref(x2d), iters=3)
-    rows.append(f"kernel_block_topk_pallas_interp,{t_pallas:.0f},n={n}")
     rows.append(f"kernel_block_topk_jnp_ref,{t_ref:.0f},n={n}")
 
     # fused update
@@ -99,10 +102,6 @@ def run(quick: bool = False, tiny: bool = False) -> List[str]:
     t_ref = timeit(lambda: jref2(th, vb, v, xi), iters=3)
     rows.append(f"kernel_fused_update_pallas_interp,{t_pallas:.0f},n={n}")
     rows.append(f"kernel_fused_update_jnp_ref,{t_ref:.0f},n={n}")
-
-    # qsgd
-    t_pallas = timeit(lambda: ops.qsgd(x, KEY, levels=16), iters=3)
-    rows.append(f"kernel_qsgd_pallas_interp,{t_pallas:.0f},n={n}")
 
     # fused compress-in-update (DESIGN.md §13)
     v = 0.1 * jax.random.normal(jax.random.fold_in(KEY, 1), (n,))

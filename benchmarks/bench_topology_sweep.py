@@ -20,7 +20,7 @@ import numpy as np
 
 from benchmarks.common import radar_world, run_method
 from repro.config import TopologyConfig, get_arch
-from repro.core.compression import Compressor
+from repro.core.compression import parse_pipeline
 from repro.core.gossip import dense_mix, plan_mixer, schedule_mix
 from repro.core.topology import (build_schedule, build_topology,
                                  dense_wire_bytes, spectral_gap)
@@ -46,7 +46,7 @@ def _payload_bytes() -> float:
     """Compressed Δθ payload for the paper's 2.7M-param LeNet @1% top-k."""
     cfg = get_arch("lenet-radar").config
     specs = jax.eval_shape(lambda: get_model(cfg).init(jax.random.PRNGKey(0)))
-    return Compressor(name="topk", ratio=0.01).wire_bytes(specs)
+    return parse_pipeline("topk", ratio=0.01).wire_bytes(specs)
 
 
 def _schedule_error(omega: np.ndarray) -> float:

@@ -13,8 +13,8 @@ For each codec pipeline this reports, over the radar LeNet parameter tree
   byte-aligned);
 * the paper's headline saving vs a dense fp32 exchange.
 
-Throughput rows time encode/decode of the pipelines and the Pallas
-pack/unpack kernels against the dense masked operator. CAVEAT (same as
+Throughput rows time encode/decode of the jnp pipeline and the Pallas
+pack/unpack kernels. CAVEAT (same as
 bench_kernels): Pallas runs interpret=True on CPU, so kernel wall time is
 NOT TPU performance — rows exist to track relative regressions.
 
@@ -33,7 +33,7 @@ import numpy as np
 
 from benchmarks.common import timeit
 from repro.config import get_arch
-from repro.core.compression import Compressor, parse_pipeline
+from repro.core.compression import parse_pipeline
 from repro.kernels import ops
 from repro.models import get_model
 
@@ -94,10 +94,6 @@ def _throughput_rows(n: int) -> List[str]:
     x = jax.random.normal(KEY, (n,))
     tree = {"w": x}
     rows = []
-    # dense masked operator (the compute-path baseline)
-    dense_op = Compressor(name="block_topk", ratio=0.01, block_size=1024)
-    t = timeit(lambda: dense_op(tree, KEY), iters=3)
-    rows.append(f"wire_dense_masked_op,{t:.0f},n={n}")
     # pipeline encode+decode (jnp path)
     pipe = parse_pipeline("block_topk", ratio=0.01, block_size=1024)
     enc = jax.jit(pipe.encode)

@@ -297,11 +297,10 @@ class FedConfig:
     burn_in: int = 700              # T_b
     rounds: int = 800               # T
     # compression
-    compressor: str = "block_topk"  # identity | topk | block_topk | qsgd | sign | randk
-    # codec pipeline DSL, e.g. "block_topk|qsgd" (sparsify then quantize the
-    # survivors). Takes precedence over the legacy ``compressor`` enum; empty
-    # string keeps the enum (back-compat). See core/compression.py.
-    pipeline: str = ""
+    # codec pipeline DSL: stages from identity | topk | block_topk | randk |
+    # qsgd | sign, chained with "|", e.g. "block_topk|qsgd" (sparsify, then
+    # quantize the survivors). See core/compression.py.
+    compressor: str = "block_topk"
     compress_ratio: float = 0.01    # paper: 1% of parameters
     qsgd_levels: int = 16
     block_size: int = 1024          # block-local top-k granularity

@@ -1,9 +1,9 @@
 """Core: the paper's contribution — CD-BFL and its baselines."""
-from repro.core.compression import (Compressor, CompressionPipeline,
-                                    FusedCodec, PerLayerPipeline,
-                                    WirePayload, encode_hbm_bytes,
-                                    leaf_stages, make_compressor,
-                                    parse_layer_rules, parse_pipeline)
+from repro.core.compression import (CompressionPipeline, FusedCodec,
+                                    PerLayerPipeline, WirePayload,
+                                    encode_hbm_bytes, leaf_stages,
+                                    make_compressor, parse_layer_rules,
+                                    parse_pipeline)
 from repro.core.mixing import mixing_matrix, adjacency, spectral_gap
 from repro.core.topology import (Topology, MixSchedule, build_topology,
                                  build_schedule, graph_adjacency,
@@ -35,8 +35,8 @@ from repro.core.posterior import (BankPredictor, SampleBank,
 from repro.core import calibration
 
 __all__ = [
-    "Compressor", "CompressionPipeline", "FusedCodec", "PerLayerPipeline",
-    "WirePayload", "encode_hbm_bytes", "leaf_stages", "make_compressor",
+    "CompressionPipeline", "FusedCodec", "PerLayerPipeline", "WirePayload",
+    "encode_hbm_bytes", "leaf_stages", "make_compressor",
     "parse_layer_rules", "parse_pipeline", "mixing_matrix", "adjacency",
     "spectral_gap", "Topology", "MixSchedule", "build_topology",
     "build_schedule", "graph_adjacency", "mixing_weights",

@@ -40,7 +40,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.compression import Compressor
+from repro.core.compression import CompressionPipeline
 from repro.core.fed_state import FedState
 from repro.core.gossip import (ShardContext, ShardMixStats,
                                resolve_participation)
@@ -149,8 +149,7 @@ class RoundMetrics(NamedTuple):
     consensus_error: jax.Array  # scalar: mean ||θ_k - θ̄||²
     delta_norm: jax.Array      # scalar: mean ||Δθ_k||²
     wire_bytes: jax.Array      # scalar: bytes/node/round on the wire
-                               # (measured from the packed payload when the
-                               # compressor is a CompressionPipeline)
+                               # (measured from the packed payload)
     cross_bytes: Any = 0.0     # scalar: bytes/node/round the mixing moved
                                # *between shards* (ppermute/all-gather rows
                                # × row bytes); 0 off the shard path
@@ -184,15 +183,15 @@ def _node_keys_for(key, node_ids) -> jax.Array:
     return jax.vmap(lambda i: jax.random.fold_in(key, i))(node_ids)
 
 
-def _compress_exchange(compressor, theta, v, key, node_ids,
-                       transport: Optional[LossyTransport] = None):
+def _compress_exchange(compressor: CompressionPipeline, theta, v, key,
+                       node_ids, transport: Optional[LossyTransport] = None):
     """Run Q per node over the residual ``theta - v``, optionally through
     the lossy frame transport; return ``(delta_v, delta_mix, bytes/node,
     tx)``.
 
     The residual is handed to the compressor as its two operands rather
-    than precomputed: pipelines encode via ``encode_pair(theta, v, key)``,
-    so a :class:`~repro.core.compression.FusedCodec` can form the delta
+    than precomputed: it encodes via ``encode_pair(theta, v, key)``, so
+    a :class:`~repro.core.compression.FusedCodec` can form the delta
     tile-locally inside the pack kernel and the dense residual never
     reaches HBM (DESIGN.md §13). The base pipeline's ``encode_pair``
     materializes ``t - v.astype(t.dtype)`` per leaf — bitwise-identical
@@ -200,12 +199,11 @@ def _compress_exchange(compressor, theta, v, key, node_ids,
 
     Node k's rows are encoded under ``fold_in(key, k)`` — its compression
     (top-k selection, QSGD norm, rand-k index set) depends only on its own
-    residual, as on a real radio. Pipelines (anything with ``encode``) go
-    through the materialized wire format: ``encode -> measured_bytes ->
-    decode``; legacy Compressors keep the dense-masked call with the
-    closed-form byte table. The payload buffers carry the local node axis,
-    so dividing by the local node count gives the per-node figure the
-    paper reports (identical on every shard).
+    residual, as on a real radio. The exchange goes through the
+    materialized wire format: ``encode -> measured_bytes -> decode``. The
+    payload buffers carry the local node axis, so dividing by the local
+    node count gives the per-node figure the paper reports (identical on
+    every shard).
 
     With a transport, the decoded delta is masked by the per-frame erasure
     draws (keys from ``fold_in(key, TRANSPORT_SALT)`` then the global node
@@ -220,27 +218,19 @@ def _compress_exchange(compressor, theta, v, key, node_ids,
     """
     keys = _node_keys_for(key, node_ids)
     local_k = node_ids.shape[0]
-    if hasattr(compressor, "encode_pair"):
-        with jax.named_scope("encode"):
-            payload = jax.vmap(compressor.encode_pair)(theta, v, keys)
-        wire = jnp.float32(payload.measured_bytes() / local_k)
-        with jax.named_scope("decode"):
-            if transport is None:
-                delta = jax.vmap(compressor.decode)(payload)
-                return delta, delta, wire, None
-            kloss = jax.random.fold_in(key, TRANSPORT_SALT)
-            tkeys = _node_keys_for(kloss, node_ids)
-            delta_full, delta_del, tx = jax.vmap(
-                partial(transport.deliver, compressor))(payload, tkeys,
-                                                        node_ids)
-        delta_v = delta_del if transport.error_feedback else delta_full
-        return delta_v, delta_del, wire, tx
-    # a dense-masked Compressor encodes and decodes in one call
     with jax.named_scope("encode"):
-        residual = jax.tree.map(lambda t, vv: t - vv.astype(t.dtype), theta, v)
-        delta = jax.vmap(compressor)(residual, keys)
-    wire = compressor.wire_bytes(jax.tree.map(lambda x: x[0], residual))
-    return delta, delta, jnp.float32(wire), None
+        payload = jax.vmap(compressor.encode_pair)(theta, v, keys)
+    wire = jnp.float32(payload.measured_bytes() / local_k)
+    with jax.named_scope("decode"):
+        if transport is None:
+            delta = jax.vmap(compressor.decode)(payload)
+            return delta, delta, wire, None
+        kloss = jax.random.fold_in(key, TRANSPORT_SALT)
+        tkeys = _node_keys_for(kloss, node_ids)
+        delta_full, delta_del, tx = jax.vmap(
+            partial(transport.deliver, compressor))(payload, tkeys, node_ids)
+    delta_v = delta_del if transport.error_feedback else delta_full
+    return delta_v, delta_del, wire, tx
 
 
 def _reduce_transport(tx: Optional[TransportMetrics],
@@ -280,16 +270,6 @@ def _participation_freeze(p_local, new_tree, old_tree):
         m = p_local.reshape((p_local.shape[0],) + (1,) * (n.ndim - 1))
         return jnp.where(m > 0.5, n, o.astype(n.dtype))
     return jax.tree.map(leaf, new_tree, old_tree)
-
-
-def _check_transport(transport: Optional[LossyTransport], compressor):
-    """Frame-level loss needs the materialized wire format."""
-    if (transport is not None and transport.lossy
-            and not hasattr(compressor, "encode")):
-        raise ValueError(
-            "frame-level transport loss requires a codec pipeline "
-            "(CompressionPipeline); the legacy dense-masked Compressor has "
-            "no wire payload to fragment — use fed_cfg.pipeline")
 
 
 def _allsum(x, shard_ctx: Optional[ShardContext]):
@@ -337,7 +317,8 @@ def _cross_bytes(mix_stats: Optional[ShardMixStats], mixed_tree,
 # CD-BFL — the paper's Algorithm 1
 # --------------------------------------------------------------------------
 
-def make_cdbfl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
+def make_cdbfl_round(loss_fn: LossFn, fed_cfg, omega,
+                     compressor: CompressionPipeline,
                      data_scale: float = 1.0, mixer=None,
                      shard_ctx: Optional[ShardContext] = None,
                      transport: Optional[LossyTransport] = None):
@@ -369,7 +350,6 @@ def make_cdbfl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
     L = fed_cfg.local_steps
     omega = jnp.asarray(omega, jnp.float32)
     transport = resolve_transport(fed_cfg, transport)
-    _check_transport(transport, compressor)
     mixer, mix_stats = _resolve_mixer(omega, fed_cfg, mixer, shard_ctx,
                                       transport)
     participation = resolve_participation(fed_cfg)
@@ -574,7 +554,8 @@ def make_dsgld_round(loss_fn: LossFn, fed_cfg, omega, data_scale: float = 1.0,
 # CF-FL — CHOCO-SGD, compressed frequentist baseline [23]
 # --------------------------------------------------------------------------
 
-def make_cffl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
+def make_cffl_round(loss_fn: LossFn, fed_cfg, omega,
+                    compressor: CompressionPipeline,
                     data_scale: float = 1.0, mixer=None,
                     shard_ctx: Optional[ShardContext] = None,
                     transport: Optional[LossyTransport] = None):
@@ -585,7 +566,6 @@ def make_cffl_round(loss_fn: LossFn, fed_cfg, omega, compressor: Compressor,
     L = fed_cfg.local_steps
     omega = jnp.asarray(omega, jnp.float32)
     transport = resolve_transport(fed_cfg, transport)
-    _check_transport(transport, compressor)
     mixer, mix_stats = _resolve_mixer(omega, fed_cfg, mixer, shard_ctx,
                                       transport)
     participation = resolve_participation(fed_cfg)
@@ -697,7 +677,8 @@ ALGORITHMS = {
 
 
 def make_round_fn(algorithm: str, loss_fn: LossFn, fed_cfg, omega,
-                  compressor: Compressor = None, data_scale: float = 1.0,
+                  compressor: Optional[CompressionPipeline] = None,
+                  data_scale: float = 1.0,
                   mixer=None, shard_ctx: Optional[ShardContext] = None,
                   transport: Optional[LossyTransport] = None):
     if algorithm == "cdbfl":

@@ -5,87 +5,30 @@ CHOCO/Koloskova analysis the paper builds on:
 
     E ||Q(x) - x||^2  <=  (1 - delta) ||x||^2,   0 < delta <= 1
 
-Two layers live here (DESIGN.md §2):
-
-* **Legacy one-shot operators** (:class:`Compressor`): act per-leaf on
-  pytrees, return *dense masked* tensors, estimate wire cost from the
-  closed-form byte table (:meth:`Compressor.wire_bytes`). Kept as the
-  reference semantics and as the cross-check for the codec layer.
-* **Composable codec pipelines** (:class:`CompressionPipeline`): chainable
-  :class:`Codec` stages (``sparsify ∘ quantize``, e.g. the DSL string
-  ``"block_topk|qsgd"``) with ``encode(tree, key) -> WirePayload`` and
-  ``decode(payload) -> tree``. The :class:`WirePayload` *materializes* the
-  packed representation that actually crosses the link — per-block value
-  buffers, uint16 block-local indices, quantization scales — and computes
-  ``measured_bytes()`` from the buffers themselves. ``decode(encode(x))``
-  is bitwise-identical to the legacy dense-masked operator for every
-  sparse codec, so pipelines are drop-in for :class:`Compressor` in the
-  round functions. Deltas compose multiplicatively.
+Q is a :class:`CompressionPipeline` (DESIGN.md §2): chainable
+:class:`Codec` stages (``sparsify ∘ quantize``, e.g. the DSL string
+``"block_topk|qsgd"``) with ``encode(tree, key) -> WirePayload`` and
+``decode(payload) -> tree``. The :class:`WirePayload` *materializes* the
+packed representation that actually crosses the link — per-block value
+buffers, uint16 block-local indices, quantization scales — and computes
+``measured_bytes()`` from the buffers themselves. Deltas compose
+multiplicatively. :class:`FusedCodec` lowers a pipeline onto the Pallas
+kernels (DESIGN.md §13).
 
 TPU adaptation: exact *global* top-k needs a global sort — hostile to VMEM
 tiling. ``block_topk`` keeps the top ``k_b`` entries of every aligned block
-instead, computable tile-locally (Pallas kernels in
-``repro.kernels.block_topk`` / ``repro.kernels.pack``) and satisfies the
-same contraction bound with delta = ratio.
+instead, computable tile-locally (the Pallas kernels of
+``repro.kernels.pack`` and ``repro.kernels.fused_compress``) and satisfies
+the same contraction bound with delta = ratio.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from functools import partial
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.utils.tree import split_key_like, tree_count
-
-
-# --------------------------------------------------------------------------
-# Leaf-level operators. Each takes (x, key) -> dense-masked x_hat.
-# --------------------------------------------------------------------------
-
-def _identity_leaf(x, key, **_):
-    return x
-
-
-def _topk_leaf(x, key, *, ratio: float, **_):
-    """Exact global top-|.| sparsification of a leaf (reference semantics).
-
-    Selection goes through ``top_k`` *indices* (ties broken deterministically
-    toward the lower index) rather than a ``mag >= thresh`` mask, so exactly
-    ``k`` entries survive even with tied magnitudes — the sparsity budget the
-    wire accounting assumes is never exceeded.
-    """
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    k = max(1, int(np.ceil(ratio * n)))
-    if k >= n:
-        return x
-    _, idx = jax.lax.top_k(jnp.abs(flat), k)
-    out = jnp.zeros_like(flat).at[idx].set(flat[idx])
-    return out.reshape(x.shape)
-
-
-def _block_topk_leaf(x, key, *, ratio: float, block_size: int, **_):
-    """Block-local top-k: each contiguous block keeps its own top entries.
-
-    Same sparsity budget as global top-k but the selection is local to a
-    block (VMEM-tile computable on TPU). Pads the tail block with zeros.
-    """
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    if n <= block_size:
-        return _topk_leaf(x, key, ratio=ratio)
-    nb = -(-n // block_size)
-    padded = jnp.pad(flat, (0, nb * block_size - n))
-    blocks = padded.reshape(nb, block_size)
-    k = max(1, int(np.ceil(ratio * block_size)))
-    # index-based selection: exactly k per block, ties -> lower index
-    _, idx = jax.lax.top_k(jnp.abs(blocks), k)
-    vals = jnp.take_along_axis(blocks, idx, axis=1)
-    out = jnp.zeros_like(blocks).at[jnp.arange(nb)[:, None], idx].set(vals)
-    return out.reshape(-1)[:n].reshape(x.shape)
 
 
 def _randk_indices(key, n: int, k: int) -> jax.Array:
@@ -99,172 +42,10 @@ def _randk_indices(key, n: int, k: int) -> jax.Array:
     return idx
 
 
-def _randk_leaf(x, key, *, ratio: float, **_):
-    """Biased (CHOCO-style) rand-k: keep exactly k = ceil(ratio·n) random
-    coordinates, NO 1/ratio rescale.
-
-    The unbiased ``mask/ratio`` variant violates the module's contraction
-    contract: E||Q(x)-x||² = (1/ratio − 1)||x||², which exceeds
-    (1 − ratio)||x||² for ratio < 0.618 — CHOCO error feedback requires the
-    biased form. With exactly k coordinates the contraction is deterministic:
-    ||Q(x)-x||² = (1 − k/n)||x||² in expectation over the uniform index set.
-    """
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    k = max(1, int(np.ceil(ratio * n)))
-    if k >= n:
-        return x
-    idx = _randk_indices(key, n, k)
-    out = jnp.zeros_like(flat).at[idx].set(flat[idx])
-    return out.reshape(x.shape)
-
-
-def _sign_leaf(x, key, **_):
-    """1-bit sign compression scaled by mean magnitude (SignSGD w/ norm)."""
-    scale = jnp.mean(jnp.abs(x))
-    return jnp.sign(x) * scale
-
-
 def _qsgd_omega(n: int, levels: int) -> float:
     """QSGD variance bound: E||q(x)-x||^2 <= omega ||x||^2 (Alistarh '17,
     Thm 3.2): omega = min(n/s^2, sqrt(n)/s)."""
     return float(min(n / levels ** 2, np.sqrt(n) / levels))
-
-
-def _qsgd_leaf(x, key, *, levels: int, **_):
-    """QSGD stochastic quantization (Alistarh et al. '17), per-leaf norm.
-
-    Scaled by 1/(1+omega) so the operator is a delta-contraction with
-    delta = 1/(1+omega) — the form CHOCO-style error feedback requires
-    (an *unbiased* high-variance q would break the control sequences).
-    """
-    norm = jnp.linalg.norm(x.reshape(-1).astype(jnp.float32)) + 1e-12
-    scaled = jnp.abs(x.astype(jnp.float32)) / norm * levels
-    lower = jnp.floor(scaled)
-    prob = scaled - lower
-    rnd = jax.random.uniform(key, x.shape)
-    q = lower + (rnd < prob).astype(jnp.float32)
-    omega = _qsgd_omega(x.size, levels)
-    out = jnp.sign(x) * q * norm / levels / (1.0 + omega)
-    return out.astype(x.dtype)
-
-
-_LEAF_OPS: Dict[str, Callable] = {
-    "identity": _identity_leaf,
-    "topk": _topk_leaf,
-    "block_topk": _block_topk_leaf,
-    "randk": _randk_leaf,
-    "sign": _sign_leaf,
-    "qsgd": _qsgd_leaf,
-}
-
-
-@dataclass(frozen=True)
-class Compressor:
-    """Pytree compression operator with wire-cost accounting.
-
-    Purity: ``compress`` is deterministic in ``(tree, key)`` — same key, same bits and same wire-byte count.
-    """
-
-    name: str = "block_topk"
-    ratio: float = 0.01
-    block_size: int = 1024
-    qsgd_levels: int = 16
-    min_dense_size: int = 0   # leaves with fewer elements are passed through
-
-    def __call__(self, tree, key):
-        """Apply Q leaf-wise. ``key`` seeds the stochastic operators."""
-        if self.name in ("block_topk_pallas", "qsgd_pallas"):
-            return self._call_pallas(tree, key)
-        op = _LEAF_OPS[self.name]
-        keys = split_key_like(key, tree)
-
-        def leaf(x, k):
-            if self.min_dense_size and x.size <= self.min_dense_size:
-                return x
-            return op(
-                x, k,
-                ratio=self.ratio,
-                block_size=self.block_size,
-                levels=self.qsgd_levels,
-            )
-
-        return jax.tree.map(leaf, tree, keys)
-
-    def _call_pallas(self, tree, key):
-        """Pallas kernel path (compiled on a TPU, interpreted elsewhere)."""
-        from repro.kernels import ops as kops
-        keys = split_key_like(key, tree)
-
-        def leaf(x, k):
-            if self.min_dense_size and x.size <= self.min_dense_size:
-                return x
-            if self.name == "block_topk_pallas":
-                return kops.block_topk(x, ratio=self.ratio,
-                                       block_size=self.block_size)
-            return kops.qsgd(x, k, levels=self.qsgd_levels)
-
-        return jax.tree.map(leaf, tree, keys)
-
-    # -- wire-format accounting (bytes actually sent over the scarce link) --
-    def wire_bytes(self, tree, elem_bytes: int = 4, index_bytes: int = 4) -> int:
-        n = tree_count(tree)
-        name = self.name.replace("_pallas", "")
-        if name == "identity":
-            return n * elem_bytes
-        if name == "randk":
-            # indices are derivable from the shared PRNG key: charge values
-            # only, plus the 8-byte key per leaf (keys split per leaf)
-            k = int(np.ceil(self.ratio * n))
-            return k * elem_bytes + 8 * len(jax.tree.leaves(tree))
-        if name in ("topk", "block_topk"):
-            k = int(np.ceil(self.ratio * n))
-            # values + indices (block_topk indices are block-local -> 2 bytes
-            # suffice for block_size <= 65536, we count 2; the normalized
-            # ``name`` covers the Pallas variant too)
-            ib = 2 if name == "block_topk" else index_bytes
-            return k * (elem_bytes + ib)
-        if name == "sign":
-            return n // 8 + 4 * len(jax.tree.leaves(tree))
-        if name == "qsgd":
-            import math
-            bits = max(1, int(np.ceil(np.log2(self.qsgd_levels + 1))) + 1)
-            return n * bits // 8 + 4 * len(jax.tree.leaves(tree))
-        raise ValueError(self.name)
-
-    @property
-    def delta(self) -> float:
-        """Contraction constant (lower bound) for analysis/tests."""
-        name = self.name.replace("_pallas", "")
-        if name == "identity":
-            return 1.0
-        if name in ("topk", "block_topk", "randk"):
-            return self.ratio
-        if name == "sign":
-            return 1e-3  # depends on leaf kurtosis; loose bound
-        if name == "qsgd":
-            return 1e-3  # conservative fallback; see delta_for(tree)
-        raise ValueError(self.name)
-
-    def delta_for(self, tree) -> float:
-        """Shape-aware contraction constant for a concrete pytree.
-
-        For qsgd the true per-leaf delta is 1/(1+ω(n, levels)) with ω from
-        Alistarh '17 Thm 3.2; the tree-level bound is the min over the leaves
-        actually compressed (min_dense_size passthrough leaves contract with
-        delta = 1). The :attr:`delta` property stays as the conservative
-        shape-free fallback.
-        """
-        name = self.name.replace("_pallas", "")
-        if name != "qsgd":
-            return self.delta
-        deltas = [1.0]
-        for x in jax.tree.leaves(tree):
-            n = int(np.prod(x.shape))
-            if self.min_dense_size and n <= self.min_dense_size:
-                continue
-            deltas.append(1.0 / (1.0 + _qsgd_omega(n, self.qsgd_levels)))
-        return float(min(deltas))
 
 
 # ==========================================================================
@@ -402,7 +183,7 @@ class BlockTopKCodec(Codec):
 
     ``use_pallas=True`` routes pack/unpack through the tile-local Pallas
     kernels (``repro.kernels.pack``, compiled on a TPU); the jnp path
-    is bitwise-identical to the legacy dense-masked ``_block_topk_leaf``.
+    emits the survivors in ``top_k`` slot order.
     """
 
     name: str = "block_topk"
@@ -421,7 +202,7 @@ class BlockTopKCodec(Codec):
             return vals, {"idx": idx}, _SparseMeta(
                 tuple(x.shape), n, vals.shape[1], "pallas",
                 nb=vals.shape[0], bs=self.block_size)
-        if n <= self.block_size:          # same fallback as the legacy op
+        if n <= self.block_size:          # one block: exact global top-k
             return TopKCodec(ratio=self.ratio).encode(x, key)
         bs = self.block_size
         assert bs <= np.iinfo(np.uint16).max + 1, "uint16 block-local indices"
@@ -506,8 +287,8 @@ class QSGDCodec(Codec):
     """QSGD stochastic quantization; int8/int16 signed grid + f32 scale.
 
     The carrier is ``sign(x)·q`` materialized at the narrowest integer
-    dtype that holds ±levels; decode reproduces the legacy `_qsgd_leaf`
-    arithmetic bitwise (same association order, same 1/(1+ω) scaling).
+    dtype that holds ±levels; decode scales it by ``norm/levels/(1+ω)``,
+    the 1/(1+ω) that makes the stage a contraction.
     """
 
     name: str = "qsgd"
@@ -554,7 +335,7 @@ class SignCodec(Codec):
     """Ternary sign code: bit-packed sign plane + nonzero-mask plane +
     mean-magnitude scale (2 bits/entry on the wire).
 
-    The explicit zero symbol makes decode reproduce the legacy dense op
+    The explicit zero symbol makes decode reproduce the dense sign operator
     bitwise — ``sign(0)·scale = 0`` included. A sign-only 1-bit plane
     would inject ±scale mass at exact-zero coordinates (common in packed
     carriers: a block with fewer than k nonzeros pads with zeros), which
@@ -672,8 +453,8 @@ class WirePayload:
 
 
 def _stage_key(leaf_key, si: int):
-    """Stage 0 uses the leaf key directly (bitwise compat with the legacy
-    single-op Compressor); later stochastic stages fold in their index."""
+    """Stage 0 uses the leaf key directly; later stochastic stages fold in
+    their index."""
     return leaf_key if si == 0 else jax.random.fold_in(leaf_key, si)
 
 
@@ -681,8 +462,7 @@ def _stage_key(leaf_key, si: int):
 class CompressionPipeline:
     """Chainable codec stages with a materialized wire format.
 
-    Drop-in for :class:`Compressor` in the round functions: ``__call__``
-    is ``decode(encode(x))``. Deltas compose multiplicatively
+    ``__call__`` is ``decode(encode(x))``. Deltas compose multiplicatively
     (Gong & Simeone '22: a δ₁-contraction followed by a δ₂-contraction of
     its output is a δ₁·δ₂-contraction).
 
@@ -794,15 +574,9 @@ class CompressionPipeline:
         return float(min(deltas))
 
     # -- wire accounting ---------------------------------------------------
-    def wire_bytes(self, tree, elem_bytes: int = 4,
-                   index_bytes: int = 4) -> int:
-        """Measured bytes for ``tree`` (static: traces encode shapes only).
-
-        Same name/signature as :meth:`Compressor.wire_bytes` so callers
-        (trainer, launch, examples) work with either object; for pipelines
-        the number comes from the materialized buffers, and
-        :meth:`formula_bytes` provides the legacy closed-form cross-check.
-        """
+    def wire_bytes(self, tree) -> int:
+        """Measured bytes for ``tree`` (static: traces encode shapes only);
+        :meth:`formula_bytes` is the closed-form cross-check."""
         key = jax.ShapeDtypeStruct((2,), jnp.uint32)
         specs = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
@@ -956,9 +730,6 @@ _CODEC_FACTORIES: Dict[str, Callable[..., Codec]] = {
     "topk": lambda ratio, block_size, levels: TopKCodec(ratio=ratio),
     "block_topk": lambda ratio, block_size, levels: BlockTopKCodec(
         ratio=ratio, block_size=block_size),
-    "block_topk_pallas": lambda ratio, block_size, levels: BlockTopKCodec(
-        name="block_topk_pallas", ratio=ratio, block_size=block_size,
-        use_pallas=True),
     "randk": lambda ratio, block_size, levels: RandKCodec(ratio=ratio),
     "qsgd": lambda ratio, block_size, levels: QSGDCodec(levels=levels),
     "sign": lambda ratio, block_size, levels: SignCodec(),
@@ -1124,41 +895,27 @@ def encode_hbm_bytes(pipeline: CompressionPipeline, theta, v=None) -> dict:
 
 
 def make_compressor(fed_cfg):
-    """Build the compression object from a FedConfig.
+    """Build the compression pipeline from a FedConfig.
 
-    ``fed_cfg.pipeline`` (the ``"a|b"`` DSL) takes precedence; otherwise
-    the legacy ``compressor`` enum maps onto a single-stage pipeline —
-    bitwise-identical output, but with a real wire format. The dense
-    Pallas variants keep the legacy :class:`Compressor` path (they
-    exercise the masked kernels end to end).
-
-    ``fed_cfg.fused_compress`` wraps the pipeline in a :class:`FusedCodec`
-    (stage 0 normalized to the Pallas pack path — see
-    :func:`_lower_stage0`); ``fed_cfg.layer_pipelines`` builds a
-    :class:`PerLayerPipeline` routing leaves by path pattern. The two
-    compose.
+    ``fed_cfg.compressor`` is the ``"a|b"`` codec DSL
+    (:func:`parse_pipeline`). ``fed_cfg.fused_compress`` wraps the
+    pipeline in a :class:`FusedCodec` (stage 0 normalized to the Pallas
+    pack path — see :func:`_lower_stage0`); ``fed_cfg.layer_pipelines``
+    builds a :class:`PerLayerPipeline` routing leaves by path pattern. The
+    two compose.
     """
-    spec = getattr(fed_cfg, "pipeline", "") or ""
-    if not spec and fed_cfg.compressor.endswith("_pallas"):
-        return Compressor(
-            name=fed_cfg.compressor,
-            ratio=fed_cfg.compress_ratio,
-            block_size=fed_cfg.block_size,
-            qsgd_levels=fed_cfg.qsgd_levels,
-            min_dense_size=fed_cfg.min_dense_size,
-        )
     kw = dict(
         ratio=fed_cfg.compress_ratio,
         block_size=fed_cfg.block_size,
         qsgd_levels=fed_cfg.qsgd_levels,
         min_dense_size=fed_cfg.min_dense_size,
     )
-    base = parse_pipeline(spec or fed_cfg.compressor, **kw)
-    fused = bool(getattr(fed_cfg, "fused_compress", False))
-    raw_rules = tuple(getattr(fed_cfg, "layer_pipelines", ()) or ())
-    if raw_rules:
+    base = parse_pipeline(fed_cfg.compressor, **kw)
+    fused = fed_cfg.fused_compress
+    if fed_cfg.layer_pipelines:
         rules = tuple(
-            (pat, parse_pipeline(sub, **kw)) for pat, sub in raw_rules)
+            (pat, parse_pipeline(sub, **kw))
+            for pat, sub in fed_cfg.layer_pipelines)
         if fused:
             rules = tuple((pat, replace(p, stages=_lower_stage0(p.stages)))
                           for pat, p in rules)

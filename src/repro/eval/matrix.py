@@ -35,7 +35,7 @@ class MatrixCell:
     scenario: str
     severity: float
     algorithm: str
-    pipeline: str          # codec DSL ("" = the legacy compressor enum)
+    pipeline: str          # codec DSL ("" = the spec's compressor)
     report: EvalReport
     train_wall_s: float = 0.0
     eval_wall_s: float = 0.0
@@ -111,7 +111,7 @@ def _train_one(spec: MatrixSpec, algorithm: str, pipeline: str):
         num_nodes=spec.nodes, local_steps=spec.local_steps, eta=spec.eta,
         zeta=spec.zeta, rounds=spec.rounds,
         burn_in=int(spec.rounds * spec.burn_in_frac),
-        compressor=spec.compressor, pipeline=pipeline,
+        compressor=pipeline or spec.compressor,
         compress_ratio=spec.compress_ratio, topology=spec.topology,
         temperature=spec.temperature, algorithm=algorithm, seed=spec.seed,
         transport=transport, participation=participation,

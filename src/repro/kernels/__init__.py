@@ -1,10 +1,12 @@
 """Pallas TPU kernels for CD-BFL's compute hot-spots.
 
-* block_topk — the paper's Q (top-k sparsification) as a VMEM-tile-local
-  threshold-bisection kernel (no sort).
+* pack — the paper's Q (block top-k sparsification) packed straight into
+  the wire format by VMEM-tile-local threshold bisection (no sort), and
+  its unpack; the bisection lives in block_topk.
+* fused_compress — the pack run on θ − v formed in VMEM, and the QSGD
+  quantization (paper ref [26]) of the packed carrier.
 * fused_update — paper Eq. 9 (consensus correction + Langevin noise) in one
   memory-bound pass.
-* qsgd — stochastic quantization (paper ref [26]) with contraction scaling.
 * flash_attention — causal GQA attention for the LM training path, forward
   and backward, with each block's scores kept in VMEM.
 

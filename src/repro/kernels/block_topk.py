@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: block-local top-k sparsification (the paper's Q).
+"""Block-local top-k selection for the Pallas pack kernels (the paper's Q).
 
 The compression hot-spot of CD-BFL: Q(θ - v) over p params every round
 (p = 2.7M for the radar model, up to 314B for grok-1 — per-shard on the
@@ -14,16 +14,15 @@ Mosaic has no ``cumsum``, so the index-order rank that breaks ties at the
 threshold is a matmul against a triangular 0/1 matrix
 (:func:`_row_prefix_count`), exact for any block size.
 
-Layout: input reshaped to (num_blocks, block_size); one grid row processes
-``ROWS_PER_TILE`` blocks; block_size is a multiple of 128 (lane width).
+These are in-kernel helpers of the pack kernel (``pack.py``) and the
+fused delta-pack kernel (``fused_compress.py``). Layout: input reshaped to
+(num_blocks, block_size); one grid row processes ``ROWS_PER_TILE`` blocks;
+block_size is a multiple of 128 (lane width).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 ROWS_PER_TILE = 8
 BISECT_ITERS = 40
@@ -68,36 +67,3 @@ def _row_prefix_count(mask):
         carry = pre[:, w - 1:w]
     out = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     return out.astype(jnp.int32)
-
-
-def _block_topk_kernel(x_ref, o_ref, *, k: int):
-    x = x_ref[...]                                     # (rows, block_size)
-    mag = jnp.abs(x.astype(jnp.float32))
-    lo, hi = _bisect_threshold(mag, k)
-    # Exact-k under ties (invariants: count(>= lo) >= k, count(>= hi) < k):
-    # everything strictly above the threshold survives, then the
-    # tied-at-threshold group fills the remaining slots in index order —
-    # the jax.lax.top_k rule, and the sparsity budget the wire accounting
-    # assumes (kernels/pack.py packs exactly these k entries).
-    mask_def = mag >= hi
-    mask_tie = (mag >= lo) & ~mask_def
-    n_def = jnp.sum(mask_def.astype(jnp.int32), axis=1, keepdims=True)
-    pos_tie = n_def + _row_prefix_count(mask_tie) - 1
-    mask = mask_def | (mask_tie & (pos_tie < k))
-    o_ref[...] = jnp.where(mask, x, jnp.zeros_like(x))
-
-
-def block_topk_pallas(x2d: jnp.ndarray, k: int, *, interpret: bool
-                      ) -> jnp.ndarray:
-    """x2d (num_blocks, block_size) -> same shape, top-k per row kept."""
-    nb, bs = x2d.shape
-    assert nb % ROWS_PER_TILE == 0, f"pad num_blocks to {ROWS_PER_TILE}"
-    grid = (nb // ROWS_PER_TILE,)
-    return pl.pallas_call(
-        functools.partial(_block_topk_kernel, k=k),
-        grid=grid,
-        in_specs=[pl.BlockSpec((ROWS_PER_TILE, bs), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((ROWS_PER_TILE, bs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, bs), x2d.dtype),
-        interpret=interpret,
-    )(x2d)

@@ -15,11 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import interpret_mode
-from repro.kernels.block_topk import ROWS_PER_TILE, block_topk_pallas
+from repro.kernels.block_topk import ROWS_PER_TILE
 from repro.kernels.fused_compress import delta_pack_pallas, grid_quant_pallas
 from repro.kernels.fused_update import TILE_C, TILE_R, fused_update_pallas
 from repro.kernels.pack import pack_topk_pallas, unpack_topk_pallas
-from repro.kernels.qsgd import qsgd_pallas
 
 
 def _pad_to_2d(x: jnp.ndarray, cols: int, row_mult: int
@@ -35,20 +34,6 @@ def _pad_to_2d(x: jnp.ndarray, cols: int, row_mult: int
 
 def _unpad(x2d: jnp.ndarray, n: int, shape) -> jnp.ndarray:
     return x2d.reshape(-1)[:n].reshape(shape)
-
-
-# --------------------------------------------------------------------------
-# block top-k
-# --------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("ratio", "block_size"))
-def block_topk(x: jnp.ndarray, ratio: float = 0.01,
-               block_size: int = 1024) -> jnp.ndarray:
-    """Leaf-level block top-k. Keeps ceil(ratio·block_size) per block."""
-    k = max(1, int(np.ceil(ratio * block_size)))
-    x2d, n = _pad_to_2d(x, block_size, ROWS_PER_TILE)
-    out = block_topk_pallas(x2d, k, interpret=interpret_mode())
-    return _unpad(out, n, x.shape)
 
 
 # --------------------------------------------------------------------------
@@ -106,29 +91,6 @@ def fused_update(theta, vbar, v, noise, zeta: float, noise_scale: float):
     out = fused_update_pallas(t2, vb2, v2, n2, zeta, noise_scale,
                               interpret=interpret_mode())
     return _unpad(out, n, theta.shape)
-
-
-# --------------------------------------------------------------------------
-# QSGD
-# --------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("levels",))
-def qsgd(x, key, levels: int = 16):
-    """Bitwise-identical to the ``_qsgd_leaf`` codec stage: eps-included
-    norm, uniforms drawn at ``x.shape`` (not the padded tile shape), and
-    the codec's ``lower + (u < prob)`` rounding inside the kernel."""
-    from repro.core.compression import _qsgd_omega
-    if x.size == 0:
-        return x
-    norm = (jnp.linalg.norm(x.reshape(-1).astype(jnp.float32))
-            + 1e-12).reshape(1, 1)
-    x2d, n = _pad_to_2d(x, TILE_C, TILE_R)
-    u2d, _ = _pad_to_2d(jax.random.uniform(key, x.shape, jnp.float32),
-                        TILE_C, TILE_R)
-    out = qsgd_pallas(x2d, u2d, norm, levels,
-                      omega=_qsgd_omega(int(np.prod(x.shape)), levels),
-                      interpret=interpret_mode())
-    return _unpad(out, n, x.shape)
 
 
 # --------------------------------------------------------------------------
@@ -198,11 +160,6 @@ def qsgd_quantize_carrier(x: jnp.ndarray, key, levels: int = 16,
 # --------------------------------------------------------------------------
 # pytree-level entry points
 # --------------------------------------------------------------------------
-
-def tree_block_topk(tree, ratio: float, block_size: int = 1024):
-    return jax.tree.map(
-        lambda x: block_topk(x, ratio=ratio, block_size=block_size), tree)
-
 
 def tree_fused_update(theta_tree, vbar_tree, v_tree, noise_tree,
                       zeta: float, noise_scale: float):

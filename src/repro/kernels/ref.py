@@ -45,18 +45,3 @@ def fused_update_ref(theta, vbar, v, noise, zeta: float, noise_scale: float):
            + zeta * (vbar.astype(jnp.float32) - v.astype(jnp.float32))
            + noise_scale * noise.astype(jnp.float32))
     return out.astype(theta.dtype)
-
-
-def qsgd_ref(x, uniform, norm, levels: int, omega: float = 0.0):
-    """`_qsgd_leaf` arithmetic with norm/uniform as explicit operands.
-
-    ``norm`` is the eps-included carrier norm (the kernel wrapper adds the
-    1e-12, matching the codec); rounding is ``lower + (u < prob)`` — the
-    codec's rule, bitwise."""
-    xf = x.astype(jnp.float32)
-    n = norm.reshape(())
-    scaled = jnp.abs(xf) / n * levels
-    lower = jnp.floor(scaled)
-    q = lower + (uniform.astype(jnp.float32) < scaled - lower).astype(
-        jnp.float32)
-    return (jnp.sign(xf) * q * n / levels / (1.0 + omega)).astype(x.dtype)

@@ -126,11 +126,9 @@ def _parse_args(argv: Optional[Sequence[str]] = None):
                     metavar="NODE:DIE[:REJOIN]",
                     help="node death timeline, e.g. '2:30' (node 2 dies at "
                          "round 30) or '2:30:60' (rejoins at 60); repeatable")
-    ap.add_argument("--compressor", default="block_topk")
-    ap.add_argument("--pipeline", default="",
+    ap.add_argument("--compressor", default="block_topk",
                     help="codec pipeline DSL, e.g. 'block_topk|qsgd' "
-                         "(overrides --compressor; stages from "
-                         "core/compression.py)")
+                         "(stages from core/compression.py)")
     ap.add_argument("--ratio", type=float, default=0.01)
     ap.add_argument("--fused-compress", action="store_true",
                     help="fuse compress-encode into the update: Q(θ−v) is "
@@ -277,7 +275,7 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
         num_nodes=args.nodes, local_steps=args.local_steps,
         eta=args.eta, zeta=args.zeta, topology=args.topology,
         topology_cfg=topo_cfg,
-        compressor=args.compressor, pipeline=args.pipeline,
+        compressor=args.compressor,
         compress_ratio=args.ratio,
         fused_compress=args.fused_compress,
         layer_pipelines=parse_layer_rules(args.layer_pipelines),
@@ -320,13 +318,12 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
         gossip_wire = active * wire * (1.0 - args.link_failure)
     else:
         gossip_wire = dense_wire_bytes(fed.num_nodes, wire)
-    q_name = fed.pipeline or fed.compressor
     print(f"arch={cfg.name} params={n_params/1e6:.2f}M nodes={fed.num_nodes} "
-          f"L={fed.local_steps} Q={q_name}@{fed.compress_ratio} "
+          f"L={fed.local_steps} Q={fed.compressor}@{fed.compress_ratio} "
           f"wire={wire/1e6:.3f}MB/node/round "
           f"(dense {n_params*4/1e6:.1f}MB, saving "
           f"{100*(1-wire/(n_params*4)):.1f}%)")
-    if hasattr(comp, "formula_bytes") and args.algorithm != "dsgld":
+    if args.algorithm != "dsgld":
         formula = comp.formula_bytes(params0)
         print(f"wire accounting: measured={wire} B/node (packed payload) "
               f"formula={formula} B/node "

@@ -79,7 +79,7 @@ def _assert_payloads_bitwise(a, b):
 # fused vs two-pass oracle: bitwise, per eligible pipeline
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("spec", ["block_topk", "block_topk_pallas",
+@pytest.mark.parametrize("spec", ["block_topk", "block_topk|sign",
                                   "block_topk|qsgd"])
 @pytest.mark.parametrize("vdtype", [jnp.float32, jnp.bfloat16])
 def test_fused_bitwise_matches_two_pass_oracle(spec, vdtype):
@@ -212,7 +212,7 @@ def test_parse_layer_rules():
 
 
 def test_make_compressor_composes_fused_and_rules():
-    fed = FedConfig(pipeline="block_topk|qsgd", fused_compress=True,
+    fed = FedConfig(compressor="block_topk|qsgd", fused_compress=True,
                     layer_pipelines=(("w0", "block_topk"),),
                     compress_ratio=RATIO, block_size=BS)
     comp = make_compressor(fed)
@@ -251,7 +251,7 @@ def _shards(sizes=(17, 20, 20, 13)):
 
 def _run_engine(engine_name, fused, rounds=8, s=4):
     fed = FedConfig(num_nodes=K, local_steps=L, eta=5e-3, zeta=0.3,
-                    burn_in=4, pipeline="block_topk|qsgd",
+                    burn_in=4, compressor="block_topk|qsgd",
                     compress_ratio=0.25, block_size=64, topology="ring",
                     algorithm="cdbfl")
     topo = build_topology(resolve_topology(fed), K)

@@ -399,18 +399,6 @@ def test_resolve_transport_explicit_override_wins():
     assert resolve_transport(FedConfig(num_nodes=4)) is None
 
 
-def test_lossy_transport_needs_a_pipeline_compressor():
-    """The legacy dense-operator Compressor has no wire to erase."""
-    from repro.core.compression import Compressor
-    fed = FedConfig(num_nodes=faults.K, topology="ring", algorithm="cdbfl",
-                    compressor="topk", compress_ratio=0.5)
-    topo = build_topology(faults.resolve_topology(fed), faults.K)
-    legacy = Compressor(name="topk", ratio=0.5)
-    with pytest.raises(ValueError, match="pipeline"):
-        make_round_fn("cdbfl", faults.linear_loss, fed, topo.omega, legacy,
-                      transport=faults.make_transport(erasure=0.3))
-
-
 def test_explicit_mixer_plus_link_outage_raises():
     fed = FedConfig(num_nodes=faults.K, topology="ring", algorithm="cdbfl",
                     compressor="topk", compress_ratio=0.5)

@@ -2,9 +2,9 @@
 
 Covers the acceptance contract of the codec layer:
 
-* ``decode(encode(x))`` is **bitwise** identical to the legacy dense-masked
-  operator for every sparse codec (and qsgd, whose int8 grid reproduces the
-  legacy arithmetic exactly);
+* ``decode(encode(x))`` is **bitwise** identical to the dense-masked
+  reference operator (``tests/codec_ref.py``) for every sparse codec (and
+  qsgd, whose int8 grid reproduces the reference arithmetic exactly);
 * ``measured_bytes()`` — computed from the actual packed buffers — matches
   the closed-form formula table (exactly for sparse codecs, within the
   byte-alignment of sub-byte grids for quantizers);
@@ -20,10 +20,12 @@ import numpy as np
 import pytest
 from hypothesis import given, strategies as st
 
+import codec_ref
 from repro.config import FedConfig
-from repro.core.compression import (Compressor, CompressionPipeline,
+from repro.core.compression import (CompressionPipeline, FusedCodec,
                                     _qsgd_omega, make_compressor,
                                     parse_pipeline)
+from repro.utils.tree import split_key_like
 
 KEY = jax.random.PRNGKey(0)
 
@@ -39,26 +41,44 @@ def _sq(t):
                for x in jax.tree.leaves(t))
 
 
+def _lowered(spec, **kw):
+    """The pipeline with stage 0 on the Pallas pack path, as the fused
+    rounds run it (two-pass: the residual is materialized)."""
+    return FusedCodec.wrap(parse_pipeline(spec, **kw), fused=False)
+
+
 # randk appears only in the expectation-averaged contraction test below:
 # its kept mass fluctuates around ratio·||x||² per realization.
 SINGLE = ["identity", "topk", "block_topk", "qsgd", "sign"]
 COMPOSED = ["block_topk|qsgd", "block_topk|sign", "topk|qsgd"]
 
+# dense-masked reference operator per codec, at the test's ratio/block
+REFERENCE = {
+    "identity": lambda x, k: x,
+    "topk": lambda x, k: codec_ref.topk(x, k, ratio=0.1),
+    "block_topk": lambda x, k: codec_ref.block_topk(x, k, ratio=0.1,
+                                                    block_size=128),
+    "randk": lambda x, k: codec_ref.randk(x, k, ratio=0.1),
+    "qsgd": lambda x, k: codec_ref.qsgd(x, k, levels=16),
+    "sign": codec_ref.sign,
+}
+
 
 # --------------------------------------------------------------------------
-# Round-trip vs the legacy dense-masked operators
+# Round-trip vs the dense-masked reference operators
 # --------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", ["identity", "topk", "block_topk", "randk",
                                   "qsgd", "sign"])
 def test_roundtrip_bitwise_vs_legacy(name):
-    """decode(encode(x)) == legacy dense-masked operator, bit for bit
-    (sign's ternary code reproduces sign(0)·scale = 0 exactly too)."""
+    """decode(encode(x)) == the dense-masked reference operator, bit for
+    bit, under the same per-leaf keys (sign's ternary code reproduces
+    sign(0)·scale = 0 exactly too)."""
     tree = _rand_tree(3)
-    legacy = Compressor(name=name, ratio=0.1, block_size=128)(tree, KEY)
+    want = jax.tree.map(REFERENCE[name], tree, split_key_like(KEY, tree))
     pipe = parse_pipeline(name, ratio=0.1, block_size=128)
     out = pipe.decode(pipe.encode(tree, KEY))
-    for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(out)):
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(out)):
         assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -113,14 +133,18 @@ def test_payload_is_jit_transparent():
     out_jit = jax.jit(pipe.decode)(p_jit)
     out = pipe.decode(p_eager)
     # jit and eager may fuse the dequant multipliers differently (1-ulp);
-    # the bitwise contract is pipeline-vs-legacy, not jit-vs-eager
+    # the bitwise contract is pipeline-vs-reference, not jit-vs-eager
     for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(out_jit)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-def test_min_dense_size_passthrough_pipeline():
+@pytest.mark.parametrize("spec,lowered", [
+    pytest.param("topk", False, id="topk"),
+    pytest.param("block_topk", True, id="block_topk-lowered")])
+def test_min_dense_size_passthrough_pipeline(spec, lowered):
     tree = {"small": jnp.ones((10,)), "big": jax.random.normal(KEY, (4096,))}
-    pipe = parse_pipeline("topk", ratio=0.01, min_dense_size=64)
+    make = _lowered if lowered else parse_pipeline
+    pipe = make(spec, ratio=0.01, min_dense_size=64)
     payload = pipe.encode(tree, KEY)
     out = pipe.decode(payload)
     np.testing.assert_array_equal(np.asarray(out["small"]), np.ones(10))
@@ -134,12 +158,23 @@ def test_min_dense_size_passthrough_pipeline():
 # Contraction: every operator and composed pipeline
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("spec", SINGLE + COMPOSED)
+# (spec, ratio, qsgd levels, stage 0 lowered onto the Pallas pack path)
+CONTRACTION = (
+    [pytest.param(s, 0.05, 16, False, id=s) for s in SINGLE + COMPOSED]
+    + [pytest.param(s, 0.05, 16, True, id=f"{s}-lowered")
+       for s in ("block_topk", "block_topk|qsgd", "block_topk|sign")]
+    + [pytest.param("topk", 0.25, 16, False, id="topk-ratio0.25"),
+       pytest.param("block_topk", 0.25, 16, False, id="block_topk-ratio0.25"),
+       pytest.param("qsgd", 0.05, 4, False, id="qsgd-levels4")])
+
+
+@pytest.mark.parametrize("spec,ratio,levels,lowered", CONTRACTION)
 @given(seed=st.integers(0, 60))
-def test_pipeline_contraction_property(spec, seed):
+def test_pipeline_contraction_property(spec, ratio, levels, lowered, seed):
     """E||Q(x) - x||² <= (1 - delta)||x||² with the shape-aware delta."""
     tree = _rand_tree(seed)
-    pipe = parse_pipeline(spec, ratio=0.05, block_size=128)
+    make = _lowered if lowered else parse_pipeline
+    pipe = make(spec, ratio=ratio, block_size=128, qsgd_levels=levels)
     out = pipe(tree, jax.random.PRNGKey(seed))
     err = sum(float(jnp.sum((a - b) ** 2))
               for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)))
@@ -154,7 +189,7 @@ def test_randk_no_rescale_regression(seed):
     expectation; with exactly k survivors it holds per-realization."""
     ratio = 0.05
     x = jax.random.normal(jax.random.PRNGKey(seed), (2048,))
-    comp = Compressor(name="randk", ratio=ratio)
+    comp = parse_pipeline("randk", ratio=ratio)
     out = comp({"w": x}, jax.random.PRNGKey(seed + 1))["w"]
     k = int(np.ceil(ratio * 2048))
     assert int(jnp.sum(out != 0)) <= k          # exactly-k, no rescale
@@ -182,7 +217,7 @@ def test_randk_contraction_in_expectation(spec):
 def test_randk_error_matches_dropped_mass():
     """Without rescale the error is exactly the dropped coordinates' mass."""
     x = jax.random.normal(KEY, (1024,))
-    out = Compressor(name="randk", ratio=0.25)({"w": x}, KEY)["w"]
+    out = parse_pipeline("randk", ratio=0.25)({"w": x}, KEY)["w"]
     dropped = float(jnp.sum(jnp.where(out == 0, x, 0.0) ** 2))
     err = float(jnp.sum((out - x) ** 2))
     np.testing.assert_allclose(err, dropped, rtol=1e-6)
@@ -199,9 +234,9 @@ def test_delta_composes_multiplicatively():
 
 
 def test_qsgd_delta_for_replaces_placeholder():
-    """Compressor.delta_for computes min_leaf 1/(1+omega); the property
-    stays as the conservative fallback."""
-    comp = Compressor(name="qsgd", qsgd_levels=16)
+    """delta_for computes min_leaf 1/(1+omega); the property stays as the
+    conservative fallback."""
+    comp = parse_pipeline("qsgd", qsgd_levels=16)
     tree = _rand_tree(0)
     want = min(1.0 / (1.0 + _qsgd_omega(int(np.prod(x.shape)), 16))
                for x in jax.tree.leaves(tree))
@@ -209,19 +244,11 @@ def test_qsgd_delta_for_replaces_placeholder():
     assert comp.delta == pytest.approx(1e-3)      # fallback unchanged
     # the shape-aware bound is tight enough to be useful
     assert comp.delta_for(tree) > comp.delta
-    # pipeline qsgd uses the same per-leaf omega
-    pipe = parse_pipeline("qsgd")
-    assert pipe.delta_for(tree) == pytest.approx(want)
-
-
-@given(seed=st.integers(0, 30))
-def test_qsgd_contraction_with_shape_aware_delta(seed):
-    tree = _rand_tree(seed)
-    comp = Compressor(name="qsgd", qsgd_levels=16)
-    out = comp(tree, jax.random.PRNGKey(seed))
-    err = sum(float(jnp.sum((a - b) ** 2))
-              for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(out)))
-    assert err <= (1 - comp.delta_for(tree)) * _sq(tree) + 1e-5
+    # leaves passed through dense contract with delta = 1: the bound is
+    # the min over the compressed leaves only
+    big = _qsgd_omega(128 * 130, 16)
+    passthrough = parse_pipeline("qsgd", min_dense_size=256)
+    assert passthrough.delta_for(tree) == pytest.approx(1.0 / (1.0 + big))
 
 
 # --------------------------------------------------------------------------
@@ -248,32 +275,18 @@ def test_measured_vs_formula_quantized(spec):
     assert f <= m <= -(-f * 8) // 6 + 4 * len(jax.tree.leaves(tree))
 
 
-def test_measured_matches_legacy_table_within_index_width():
-    """The legacy Compressor byte table charged 4-byte global indices;
-    the payload narrows them to uint16 where the leaf allows. Bounded by
-    the index-width difference, never more."""
-    tree = _rand_tree(0)
-    for name, ratio in [("topk", 0.1), ("block_topk", 0.1), ("randk", 0.1)]:
-        legacy = Compressor(name=name, ratio=ratio,
-                            block_size=128).wire_bytes(tree)
-        measured = parse_pipeline(name, ratio=ratio,
-                                  block_size=128).wire_bytes(tree)
-        k_total = sum(max(1, int(np.ceil(ratio * x.size)))
-                      for x in jax.tree.leaves(tree))
-        assert abs(measured - legacy) <= 2 * k_total + 8 * 3
-
-
 @pytest.mark.parametrize("n", [64, 2048, 8 * 1024])
 def test_block_topk_pallas_measured_equals_formula(n):
-    """Regression: the pallas payload must not carry the kernel's
+    """Regression: the Pallas pack payload must not carry the kernel's
     ROWS_PER_TILE padding rows — measured == formula for every leaf size,
-    and the round-trip still matches the dense masked kernel."""
-    from repro.kernels import ops
+    and the round-trip matches the bisection reference."""
+    from repro.kernels import ops, ref
     tree = {"w": jax.random.normal(KEY, (n,))}
-    pipe = parse_pipeline("block_topk_pallas", ratio=0.01, block_size=1024)
+    pipe = _lowered("block_topk", ratio=0.01, block_size=1024)
     payload = pipe.encode(tree, KEY)
     assert payload.measured_bytes() == pipe.formula_bytes(tree)
-    dense = ops.block_topk(tree["w"], ratio=0.01, block_size=1024)
+    x2d, _ = ops._pad_to_2d(tree["w"], 1024, 8)
+    dense = ref.block_topk_bisect_ref(x2d, 11).reshape(-1)[:n]
     np.testing.assert_array_equal(np.asarray(pipe.decode(payload)["w"]),
                                   np.asarray(dense))
 
@@ -291,10 +304,9 @@ def test_randk_wire_bytes_values_only():
     """randk charges values + the 8-byte key, not k·(elem+index)."""
     tree = {"w": jnp.zeros((100_000,))}
     k = int(np.ceil(0.01 * 100_000))
-    legacy = Compressor(name="randk", ratio=0.01).wire_bytes(tree)
-    assert legacy == k * 4 + 8
-    measured = parse_pipeline("randk", ratio=0.01).wire_bytes(tree)
-    assert measured == k * 4 + 8
+    pipe = parse_pipeline("randk", ratio=0.01)
+    assert pipe.wire_bytes(tree) == k * 4 + 8
+    assert pipe.formula_bytes(tree) == k * 4 + 8
 
 
 def test_wire_payload_99_percent_saving_measured():
@@ -327,15 +339,28 @@ def test_pipeline_dsl_validation():
 # --------------------------------------------------------------------------
 
 def test_make_compressor_pipeline_precedence():
-    fed = FedConfig(compressor="topk", pipeline="block_topk|qsgd",
-                    compress_ratio=0.05)
+    """``compressor`` is the codec DSL: one name is a single-stage
+    pipeline, ``a|b`` chains stages, and an unknown stage is refused."""
+    fed = FedConfig(compressor="block_topk|qsgd", compress_ratio=0.05)
     comp = make_compressor(fed)
     assert isinstance(comp, CompressionPipeline)
     assert comp.spec == "block_topk|qsgd"
-    # enum maps to a single-stage pipeline; pallas enum keeps the legacy op
+    assert comp.stages[0].ratio == 0.05
     assert make_compressor(FedConfig(compressor="topk")).spec == "topk"
-    assert isinstance(make_compressor(FedConfig(
-        compressor="block_topk_pallas")), Compressor)
+    with pytest.raises(ValueError, match="unknown codec"):
+        make_compressor(FedConfig(compressor="block_topk_pallas"))
+
+
+def test_compressor_flag_is_the_pipeline_dsl():
+    """The launcher's one codec flag takes the DSL straight into
+    ``FedConfig.compressor``; the old ``--pipeline`` spelling is gone."""
+    from repro.launch.train import _parse_args
+    args = _parse_args(["--compressor", "block_topk|qsgd"])
+    comp = make_compressor(FedConfig(compressor=args.compressor))
+    assert comp.spec == "block_topk|qsgd"
+    assert _parse_args([]).compressor == "block_topk"
+    with pytest.raises(SystemExit):
+        _parse_args(["--pipeline", "block_topk|qsgd"])
 
 
 def test_round_metrics_report_wire_bytes():
@@ -356,7 +381,7 @@ def test_round_metrics_report_wire_bytes():
         return jnp.mean((pred - batch["y"]) ** 2), ()
 
     fed = FedConfig(num_nodes=K, local_steps=L, eta=1e-3, zeta=0.3,
-                    pipeline="topk|qsgd", compress_ratio=0.5,
+                    compressor="topk|qsgd", compress_ratio=0.5,
                     topology="ring", algorithm="cdbfl")
     topo = build_topology(resolve_topology(fed), K)
     comp = make_compressor(fed)
